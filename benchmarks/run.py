@@ -7,13 +7,15 @@ Usage::
 Prints ``name,us_per_call,derived`` CSV rows; ``--json PATH`` additionally
 writes every collected row as a machine-readable artifact (the CI uploads
 ``BENCH_fusion.json`` from the full job so the perf trajectory is
-diffable across commits).
+diffable across commits).  A module that fails to import or run is
+reported and makes this script exit non-zero (no artifact is written).
 """
 
 from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 from .common import header, write_json
 
@@ -45,18 +47,23 @@ def main() -> None:
         json_path = argv[i + 1]
         argv = argv[:i] + argv[i + 2:]
     want = argv or MODULES
+    from repro import compile_cache
+    compile_cache.enable()
     header()
+    failed = []
     for name in want:
         t0 = time.perf_counter()
-        try:
-            mod = importlib.import_module(f"benchmarks.{name}")
-        except ImportError as e:
-            print(f"# skip {name}: {e}", flush=True)
-            continue
         print(f"# --- {name} ---", flush=True)
-        mod.main()
+        try:
+            importlib.import_module(f"benchmarks.{name}").main()
+        except Exception:                  # noqa: BLE001 — reported below
+            traceback.print_exc()
+            failed.append(name)
+            continue
         print(f"# {name} done in {time.perf_counter() - t0:.1f}s",
               flush=True)
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
     if json_path is not None:
         write_json(json_path, modules=want)
 

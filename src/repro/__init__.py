@@ -6,9 +6,3 @@ multi-pod JAX training/serving framework.
 """
 
 __version__ = "0.1.0"
-
-# Installed for every entrypoint (tests, dry-run subprocesses, CLIs):
-# backfills jax.sharding.AxisType / make_mesh(axis_types=) on older JAX.
-# Imports jax but never initializes a backend — XLA_FLAGS set by an
-# entrypoint after this still take effect at first device use.
-from repro.dist import compat as _compat  # noqa: E402,F401

@@ -79,10 +79,7 @@ def _mesh_of(layout):
 def _is_real_mesh(mesh) -> bool:
     """True for an executable jax Mesh (vs an abstract LogicalMesh used
     for cost-only planning, or None)."""
-    try:
-        from jax.sharding import Mesh
-    except ImportError:                            # pragma: no cover
-        return False
+    from jax.sharding import Mesh
     return isinstance(mesh, Mesh)
 
 
@@ -960,9 +957,13 @@ def plan_fallbacks(plan: ExecPlan, layout=None, pallas: str = "never",
     Replays the same :func:`~repro.kernels.distributed.plan_segment`
     validation the staged lowering runs (via the shared
     :func:`_segment_items`), so the report can never drift from what
-    execution does.  Value-format downgrades (a sparse operand whose
-    block rows don't partition) depend on the bound arrays and are
-    recorded at call time on :attr:`CompiledPlan.fallbacks`;
+    execution does.  Under a Pallas policy it also names every dense
+    operator that takes its XLA body instead of its kernel (``kernel``
+    site, :func:`~repro.kernels.ops.kernel_fallback` at the shapes the
+    kernel would see — shard-local inside a segment).  Value-format
+    downgrades (a sparse operand whose block rows don't partition)
+    depend on the bound arrays and are recorded at call time on
+    :attr:`CompiledPlan.fallbacks`;
     ``Compiled.explain()`` merges both."""
     cache = cache if cache is not None else PLAN_CACHE
     out: list[dict] = []
@@ -970,31 +971,49 @@ def plan_fallbacks(plan: ExecPlan, layout=None, pallas: str = "never",
         out.append({"site": "plan",
                     "reason": "staged=False: per-operator debug "
                               "dispatch requested"})
-    mesh = _mesh_of(layout)
-    if mesh is None:
-        return out
-    from repro.kernels.distributed import (SegmentFallback, SegmentItem,
-                                           plan_segment)
     graph = plan.graph
-    seg_member = {j for seg in plan.segments for j in seg.indices}
-    for seg in plan.segments:
-        items = _segment_items(graph, plan, seg, cache)
-        sp = plan_segment(items, mesh)
-        if isinstance(sp, SegmentFallback):
-            out.append({"site": "segment", "specs": list(seg.indices),
-                        "reason": sp.reason})
+    #: operand shapes each fused operator's kernel sees (shard-local
+    #: inside realizable segments)
+    shapes: dict[int, dict] = {}
+    mesh = _mesh_of(layout)
+    if mesh is not None:
+        from repro.kernels.distributed import (SegmentFallback, SegmentItem,
+                                               plan_segment)
+        seg_member = {j for seg in plan.segments for j in seg.indices}
+        for seg in plan.segments:
+            items = _segment_items(graph, plan, seg, cache)
+            sp = plan_segment(items, mesh)
+            if isinstance(sp, SegmentFallback):
+                out.append({"site": "segment", "specs": list(seg.indices),
+                            "reason": sp.reason})
+            else:
+                for k, j in enumerate(seg.indices):
+                    shapes[j] = sp.local_shapes(k)
+        for idx, spec in enumerate(plan.specs):
+            if idx in seg_member:
+                continue
+            pl = getattr(spec, "placement", None)
+            if pl is None or pl.arm != "distributed":
+                continue
+            _op, cplan = cache.get_or_build(graph, spec)
+            sp = plan_segment(
+                [SegmentItem(cplan, pl, _spec_roots(spec), True)], mesh)
+            if isinstance(sp, SegmentFallback):
+                out.append({"site": "operator", "specs": [idx],
+                            "reason": sp.reason})
+            else:
+                shapes[idx] = sp.local_shapes(0)
     for idx, spec in enumerate(plan.specs):
-        if idx in seg_member:
-            continue
-        pl = getattr(spec, "placement", None)
-        if pl is None or pl.arm != "distributed":
+        if pallas == "never" or not (isinstance(spec, MultiAggSpec) or (
+                isinstance(spec, FusedOpSpec) and spec.fused)):
             continue
         _op, cplan = cache.get_or_build(graph, spec)
-        sp = plan_segment(
-            [SegmentItem(cplan, pl, _spec_roots(spec), True)], mesh)
-        if isinstance(sp, SegmentFallback):
-            out.append({"site": "operator", "specs": [idx],
-                        "reason": sp.reason})
+        if cplan.main.sparsity < 1.0:
+            continue            # the planner saw a sparse main: BCSR rows
+        reason = kops.kernel_fallback(cplan, shapes.get(idx) or {
+            b.nid: tuple(b.shape) for b in cplan.binds})
+        if reason is not None:
+            out.append({"site": "kernel", "specs": [idx], "reason": reason})
     return out
 
 

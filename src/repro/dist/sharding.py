@@ -54,8 +54,6 @@ from typing import Any, Optional
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compat  # noqa: F401  (installs AxisType/make_mesh shims)
-
 TP_AXIS = "model"
 
 

@@ -32,9 +32,27 @@ class HardwareSpec:
     #: fraction of HBM usable for program state (rest: XLA scratch,
     #: fragmentation) — the layout planner's feasibility threshold.
     hbm_usable: float = 0.9
+    #: Mosaic's default scoped-VMEM limit for one Pallas kernel
+    vmem_scoped_bytes: int = 16 * 2**20
 
 
+#: published peaks of one TPU v5e chip (Google Cloud documentation,
+#: "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s)
 TPU_V5E = HardwareSpec()
+
+#: specs by ``jax.Device.device_kind``; a kind missing here is unknown
+#: hardware, never a v5e default
+BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def spec_for(device_kind: str) -> HardwareSpec:
+    """The spec of a device as JAX reports its kind; KeyError if the
+    table does not cover it."""
+    try:
+        return BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise KeyError(f"no hardware spec for device kind {device_kind!r}; "
+                       f"known: {sorted(BY_DEVICE_KIND)}") from None
 
 
 # ---------------------------------------------------------------------------

@@ -21,13 +21,57 @@ from repro.core.cplan import (CPlan, COL_AGG, FULL_AGG, NO_AGG, ROW_AGG)
 from . import ref
 
 
-def pick_block(dim: int, target: int) -> int:
-    """Largest divisor of ``dim`` ≤ target (hardware path would mask
-    instead; divisibility keeps the validated kernels exact)."""
-    b = min(dim, target)
-    while dim % b:
-        b -= 1
-    return b
+#: Mosaic tiles the last two dims of every block by (8, 128) for f32
+#: (sublanes x lanes): each block dim is a multiple of its quantum or the
+#: array's full dimension, or the kernel does not lower.
+SUBLANE, LANE = 8, 128
+#: tile targets (rows, cols) of the Cell and MAgg skeletons
+CELL_TILE = (256, 512)
+
+
+def pick_block(dim: int, target: int, quantum: int) -> int:
+    """Block length along one axis of length ``dim``: the whole axis when
+    it fits ``target``, else the largest multiple of ``quantum`` that is
+    at most ``target`` and divides ``dim``, else the whole axis — the only
+    other legal block (:func:`vmem_bytes` then decides whether it fits).
+    Divisibility keeps every tile full, so the kernels need no masking."""
+    if dim <= target:
+        return dim
+    for b in range(target - target % quantum, 0, -quantum):
+        if dim % b == 0:
+            return b
+    return dim
+
+
+def vmem_bytes(blocks, itemsize: int = 4) -> int:
+    """Scoped VMEM the pipelined blocks of one kernel occupy: two buffers
+    per block (the pipeline prefetches the next while computing the
+    current), each padded up to whole (8, 128) tiles."""
+    pad = lambda d, q: -(-d // q) * q
+    return sum(2 * pad(r, SUBLANE) * pad(c, LANE) * itemsize
+               for r, c in blocks)
+
+
+def _block_shape(shape, bm: int, bn: int) -> tuple[int, int]:
+    """Block of a broadcast-compatible operand under (bm, bn) tiling of
+    the main input (mirrors :func:`_tile_spec`)."""
+    r, c = shape
+    return (1 if r == 1 else bm, 1 if c == 1 else bn)
+
+
+def cell_blocks(cplan: CPlan, shapes: dict):
+    """(bm, bn, block shapes of every input then the output) of the Cell
+    and MAgg skeletons at these operand shapes (nid -> (rows, cols))."""
+    m, n = shapes[cplan.main.nid]
+    bm = pick_block(m, CELL_TILE[0], SUBLANE)
+    bn = pick_block(n, CELL_TILE[1], LANE)
+    ins = [_block_shape(shapes[b.nid], bm, bn) for b in cplan.binds]
+    if cplan.extra:
+        out = (1 + len(cplan.extra), 1)
+    else:
+        out = {NO_AGG: (bm, bn), ROW_AGG: (bm, 1), COL_AGG: (1, bn),
+               FULL_AGG: (1, 1)}[cplan.variant]
+    return bm, bn, ins + [out]
 
 
 def _tile_spec(shape, m, n, bm, bn, reduce_over_rows: bool):
@@ -53,11 +97,11 @@ _COMB = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum,
 
 
 def cell_pallas(cplan: CPlan, env: dict[int, jnp.ndarray], *,
-                interpret: bool = False,
-                block: tuple[int, int] = (256, 512)) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     main = env[cplan.main.nid]
     m, n = main.shape
-    bm, bn = pick_block(m, block[0]), pick_block(n, block[1])
+    bm, bn, _ = cell_blocks(
+        cplan, {b.nid: env[b.nid].shape for b in cplan.binds})
     variant, agg = cplan.variant, (cplan.agg_op or "sum")
     reduce_rows = variant == COL_AGG      # reduce over m → rows innermost
 

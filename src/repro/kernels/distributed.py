@@ -133,10 +133,19 @@ class SegmentPlan:
     ext: tuple                       # external bind nids, in order
     ext_shard: dict                  # nid -> row-sharded?
     epilogues: tuple                 # exported items' epilogues
-    #: per-item shard-local main-row count — the row-partitioned shape
-    #: the Pallas template lowerings derive their BlockSpecs from
-    shard_rows: tuple = ()
     cache_token: tuple = field(default=(), repr=False)
+
+    def local_shapes(self, k: int) -> dict:
+        """Operand shapes (nid -> (rows, cols)) member ``k`` sees inside
+        the ``shard_map`` body: row-sharded operands and the row panels
+        of earlier ``"none"``-epilogue members arrive shard-local."""
+        panels = {nid for nid, sh in self.ext_shard.items() if sh}
+        for prev in self.items[:k]:
+            if prev.placement.epilogue == "none":
+                panels.update(prev.roots)
+        return {b.nid: ((b.shape[0] // self.n, b.shape[1])
+                        if b.nid in panels else tuple(b.shape))
+                for b in self.items[k].cplan.binds}
 
 
 def _realizable_axes(mesh, placement):
@@ -161,13 +170,9 @@ def plan_segment(items: list[SegmentItem], mesh):
         faults.fault_point("dist.segment")
     except faults.FaultInjected as e:
         return SegmentFallback(f"injected fault: {e}")
-    try:
-        from jax.sharding import Mesh
-    except ImportError:                            # pragma: no cover
-        return SegmentFallback("jax.sharding unavailable in this runtime")
     if not items:
         return SegmentFallback("empty segment")
-    if not isinstance(mesh, Mesh):
+    if not isinstance(mesh, jax.sharding.Mesh):
         return SegmentFallback(
             "abstract mesh (cost-only layout): distributed placement is "
             "costed and reported but the body runs locally")
@@ -213,17 +218,12 @@ def plan_segment(items: list[SegmentItem], mesh):
     if not any(it.export for it in items):
         return SegmentFallback("segment exports no value")
     epilogues = tuple(it.placement.epilogue for it in items if it.export)
-    shard_rows = tuple(
-        it.cplan.main.shape[0] // n
-        if it.cplan.main.nid in it.placement.sharded
-        else it.cplan.main.shape[0]
-        for it in items)
     token = (tuple(it.cplan.cache_key() for it in items), mesh, axes,
              tuple(ext), tuple(sorted(ext_shard.items())),
              tuple((it.placement.epilogue, it.export, it.roots)
                    for it in items))
     return SegmentPlan(tuple(items), axes, n, tuple(ext), ext_shard,
-                       epilogues, shard_rows, token)
+                       epilogues, token)
 
 
 def _replicated_spec(value, P):
@@ -246,7 +246,6 @@ def lower_segment(sp: SegmentPlan, mesh, values=None, *,
     caller records the reason and runs the members locally — numerically
     identical, collectives are exact)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     axes = sp.axes
     if values is None:
@@ -292,8 +291,7 @@ def lower_segment(sp: SegmentPlan, mesh, values=None, *,
                       else P() for it in sp.items if it.export)
     steps = [(it.cplan, [b.nid for b in it.cplan.binds],
               _collective(it.placement.epilogue, axes), it.roots,
-              it.export, m_loc)
-             for it, m_loc in zip(sp.items, sp.shard_rows)]
+              it.export) for it in sp.items]
 
     def body(*arrs):
         # each member's generated operator body on the local row panels;
@@ -304,9 +302,9 @@ def lower_segment(sp: SegmentPlan, mesh, values=None, *,
         env = {nid: (v.local_bcsr() if isinstance(v, ShardedBCSR) else v)
                for nid, v in zip(sp.ext, arrs)}
         outs = []
-        for cplan, nids, reduce_fn, roots, export, m_loc in steps:
+        for cplan, nids, reduce_fn, roots, export in steps:
             out = kops.execute(cplan, {nid: env[nid] for nid in nids},
-                               pallas=pallas, shard_rows=m_loc)
+                               pallas=pallas)
             if reduce_fn is not None:
                 out = reduce_fn(out)
             if len(roots) > 1:                     # combined multi-agg
@@ -318,8 +316,8 @@ def lower_segment(sp: SegmentPlan, mesh, values=None, *,
                 outs.append(out)
         return tuple(outs)
 
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)
 
 
 def prepare_segment_values(sp: SegmentPlan, values):

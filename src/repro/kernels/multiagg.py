@@ -14,15 +14,15 @@ from jax.experimental import pallas as pl
 
 from repro.core.cplan import CPlan
 from . import ref
-from .cellwise import pick_block, _tile_spec, _COMB
+from .cellwise import cell_blocks, _tile_spec, _COMB
 
 
 def multiagg_pallas(cplan: CPlan, env: dict[int, jnp.ndarray], *,
-                    interpret: bool = False,
-                    block: tuple[int, int] = (256, 512)) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     main = env[cplan.main.nid]
     m, n = main.shape
-    bm, bn = pick_block(m, block[0]), pick_block(n, block[1])
+    bm, bn, _ = cell_blocks(
+        cplan, {b.nid: env[b.nid].shape for b in cplan.binds})
 
     roots = [cplan.prog_root] + [r for r, _ in cplan.extra]
     aggs = [cplan.agg_op] + [op for _, op in cplan.extra]

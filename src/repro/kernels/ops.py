@@ -49,15 +49,15 @@ faults.register_site(
 # --------------------------------------------------------------------------
 
 def execute(cplan: CPlan, env: dict[int, object], *,
-            pallas: str = "never",
-            shard_rows: Optional[int] = None) -> jnp.ndarray:
+            pallas: str = "never") -> jnp.ndarray:
     """Run one fused operator.  ``pallas`` ∈ {"never","interpret","tpu"}.
 
-    ``shard_rows`` is the shard-local main-row count when this operator
-    executes inside a ``shard_map`` body: the Pallas template lowerings
-    derive their grids and BlockSpecs from it (largest divisor ≤ the
-    template's tile target) instead of the global-tuned defaults, so the
-    generated kernels lower as ``pallas_call`` inside the region."""
+    Inside a ``shard_map`` body the operands are the shard-local panels,
+    so the Pallas template lowerings derive their grids and BlockSpecs
+    from the shard-local shapes and lower as ``pallas_call`` inside the
+    region.  A dense operator whose kernel cannot be tiled at its shapes
+    takes its XLA body; :func:`kernel_fallback` names the reason, which
+    ``explain()["execution"]["fallbacks"]`` reports."""
     if pallas != "never":
         faults.fault_point("kernels.pallas_call")
     main = env.get(cplan.main.nid)
@@ -70,9 +70,8 @@ def execute(cplan: CPlan, env: dict[int, object], *,
         main = env[cplan.main.nid]
     if isinstance(main, BCSR):
         has_mm = any(op == "matmul" for (_, op, *_rest) in cplan.prog)
-        from repro.core.templates import TType as _T
-        if cplan.main.exploit and (cplan.ttype == _T.OUTER or not has_mm):
-            if pallas != "never" and cplan.ttype == _T.OUTER \
+        if cplan.main.exploit and (cplan.ttype == TType.OUTER or not has_mm):
+            if pallas != "never" and cplan.ttype == TType.OUTER \
                     and cplan.variant in (RIGHT_MM, FULL_AGG):
                 from .outerprod import outer_pallas
                 return outer_pallas(cplan, env,
@@ -82,26 +81,39 @@ def execute(cplan: CPlan, env: dict[int, object], *,
         env[cplan.main.nid] = main.todense()   # not exploitable: decompress
     env = {k: (v.todense() if isinstance(v, (BCSR, DictCompressed)) else v)
            for k, v in env.items()}
-    if pallas != "never":
+    if pallas != "never" and kernel_fallback(
+            cplan, {b.nid: env[b.nid].shape for b in cplan.binds}) is None:
         from . import cellwise, multiagg, rowwise
-        from .cellwise import pick_block
         interpret = pallas == "interpret"
         if cplan.extra:
-            block = (256, 512) if shard_rows is None else \
-                (pick_block(shard_rows, 256), 512)
-            return multiagg.multiagg_pallas(cplan, env, interpret=interpret,
-                                            block=block)
-        if cplan.ttype in (TType.CELL, TType.MAGG):
-            block = (256, 512) if shard_rows is None else \
-                (pick_block(shard_rows, 256), 512)
-            return cellwise.cell_pallas(cplan, env, interpret=interpret,
-                                        block=block)
+            return multiagg.multiagg_pallas(cplan, env, interpret=interpret)
         if cplan.ttype == TType.ROW:
-            br = 128 if shard_rows is None else pick_block(shard_rows, 128)
-            return rowwise.row_pallas(cplan, env, interpret=interpret,
-                                      block_rows=br)
-        # Outer over dense main: fall through to the XLA path
+            return rowwise.row_pallas(cplan, env, interpret=interpret)
+        return cellwise.cell_pallas(cplan, env, interpret=interpret)
     return ref.execute_dense(cplan, env)
+
+
+def kernel_fallback(cplan: CPlan, shapes: dict) -> Optional[str]:
+    """Why a dense fused operator takes its XLA body instead of its Pallas
+    kernel at these operand shapes (nid -> (rows, cols)), or None when
+    the kernel applies.  The pipelined blocks may fill half of the
+    chip's scoped VMEM; the program's tile temporaries get the rest."""
+    from repro.hw import TPU_V5E
+    from .cellwise import cell_blocks, vmem_bytes
+    from .rowwise import row_blocks
+    if cplan.ttype == TType.OUTER:
+        return ("Outer template over a dense main: the Outer kernel is "
+                "sparsity-exploiting only (BCSR mains); XLA body")
+    if cplan.extra or cplan.ttype in (TType.CELL, TType.MAGG):
+        blocks = cell_blocks(cplan, shapes)[2]
+    else:
+        blocks = row_blocks(cplan, shapes)[1]
+    need, budget = vmem_bytes(blocks), TPU_V5E.vmem_scoped_bytes // 2
+    if need > budget:
+        return (f"{cplan.ttype.name} blocks {blocks} need {need} B of VMEM "
+                f"> {budget} B: no (8,128)-aligned block divides "
+                f"{shapes[cplan.main.nid]}; XLA body")
+    return None
 
 
 # --------------------------------------------------------------------------

@@ -78,6 +78,9 @@ def outer_pallas(cplan: CPlan, env: dict[int, object], *,
             closer = closer.T
         k_out = closer.shape[1]
         in_specs.append(pl.BlockSpec((bs, k_out), v_map))   # V-side gather
+        # zeros aliased to the output, never read by the kernel: block
+        # rows no grid step visits keep them
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         out_spec = pl.BlockSpec((bs, k_out), u_map)
         out_shape = jax.ShapeDtypeStruct((m, k_out), dtype)
     elif variant == FULL_AGG:
@@ -95,7 +98,7 @@ def outer_pallas(cplan: CPlan, env: dict[int, object], *,
 
     def kernel(rows, cols, *refs):
         if variant == RIGHT_MM:
-            *ins, cls, out = refs
+            *ins, cls, _zeros, out = refs
         else:
             *ins, out = refs
             cls = None
@@ -156,16 +159,13 @@ def outer_pallas(cplan: CPlan, env: dict[int, object], *,
         num_scalar_prefetch=2, grid=(nb,), in_specs=in_specs,
         out_specs=out_spec)
     args = [X.data, _dense(fu), _dense(fv)] + sides
+    aliases = {}
     if variant == RIGHT_MM:
-        args.append(closer)
+        args += [closer, jnp.zeros((m, k_out), dtype)]
+        aliases = {len(args) + 1: 0}      # +2 scalar-prefetch operands
     out = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                         input_output_aliases=aliases,
                          interpret=interpret)(X.rows, X.cols, *args)
-    if variant == RIGHT_MM:
-        # rows may not cover every block-row; zero rows handled by scatter
-        # semantics of revisit-accumulate only for visited rows: fix by
-        # masking unvisited rows to zero.
-        visited = jnp.zeros((m // bs,), jnp.bool_).at[X.rows].set(True)
-        out = jnp.where(jnp.repeat(visited, bs)[:, None], out, 0)
     if variant == NO_AGG:
         return BCSR(out, X.rows, X.cols, X.shape, bs)
     return out

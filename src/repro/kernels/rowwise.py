@@ -19,50 +19,54 @@ from jax.experimental import pallas as pl
 from repro.core.cplan import (CPlan, COL_AGG, COL_T_AGG, FULL_AGG, NO_AGG,
                               ROW_AGG)
 from . import ref
-from .cellwise import pick_block, _COMB
+from .cellwise import SUBLANE, pick_block, _COMB
+
+#: row-panel target of the Row skeleton (a panel holds whole rows)
+ROW_TILE = 128
+
+
+def row_blocks(cplan: CPlan, shapes: dict):
+    """(bm, block shapes of every input then the output) of the Row
+    skeleton at these operand shapes (nid -> (rows, cols)).  Side inputs
+    with the main's m rows ride along as (bm, c) panels; anything else
+    stays fully resident."""
+    m, n = shapes[cplan.main.nid]
+    bm = pick_block(m, ROW_TILE, SUBLANE)
+    ins = []
+    for b in cplan.binds:
+        r, c = shapes[b.nid]
+        ins.append((bm, n) if b.nid == cplan.main.nid
+                   else (bm if r == m and m > 1 else r, c))
+    v = cplan.variant
+    out = {NO_AGG: (bm, cplan.out_shape[1]), ROW_AGG: (bm, 1),
+           COL_AGG: (1, cplan.out_shape[1]), FULL_AGG: (1, 1),
+           COL_T_AGG: tuple(cplan.out_shape)}[v]
+    return bm, ins + [out]
 
 
 def row_pallas(cplan: CPlan, env: dict[int, jnp.ndarray], *,
-               interpret: bool = False, block_rows: int = 128) -> jnp.ndarray:
-    main = env[cplan.main.nid]
-    m, n = main.shape
-    bm = pick_block(m, block_rows)
-    variant, agg = cplan.variant, (cplan.agg_op or "sum")
-
+               interpret: bool = False) -> jnp.ndarray:
+    m = env[cplan.main.nid].shape[0]
     binds = list(cplan.binds)
     arrays = [jnp.asarray(env[b.nid]) for b in binds]
+    bm, blocks = row_blocks(cplan, {b.nid: a.shape
+                                    for b, a in zip(binds, arrays)})
+    variant, agg = cplan.variant, (cplan.agg_op or "sum")
     dtype = arrays[0].dtype
-    in_specs = []
-    for b, a in zip(binds, arrays):
-        r, c = a.shape
-        if b.nid == cplan.main.nid:
-            in_specs.append(pl.BlockSpec((bm, n), lambda i: (i, 0)))
-        elif r == m and m > 1:                 # row-aligned side panel
-            in_specs.append(pl.BlockSpec((bm, c), lambda i: (i, 0)))
-        else:                                  # fully-resident side input
-            in_specs.append(pl.BlockSpec((r, c), lambda i: (0, 0)))
+    in_specs = [pl.BlockSpec(blk, (lambda i: (i, 0)) if blk[0] == bm
+                             and a.shape[0] == m else (lambda i: (0, 0)))
+                for a, blk in zip(arrays, blocks)]
     nid_to_pos = {b.nid: i for i, b in enumerate(binds)}
 
     roots = [cplan.prog_root]
     if cplan.close_nid is not None:
         roots.append(cplan.close_nid)
 
-    # output geometry
-    if variant == NO_AGG:
-        n_out = cplan.out_shape[1]
-        out_spec = pl.BlockSpec((bm, n_out), lambda i: (i, 0))
-        out_shape = (m, n_out)
-    elif variant == ROW_AGG:
-        out_spec = pl.BlockSpec((bm, 1), lambda i: (i, 0))
-        out_shape = (m, 1)
-    elif variant in (COL_AGG, FULL_AGG):
-        out_shape = (1, cplan.out_shape[1]) if variant == COL_AGG else (1, 1)
-        out_spec = pl.BlockSpec(out_shape, lambda i: (0, 0))
-    elif variant == COL_T_AGG:
-        out_shape = cplan.out_shape
-        out_spec = pl.BlockSpec(out_shape, lambda i: (0, 0))
-    else:
-        raise NotImplementedError(variant)
+    out_blk = blocks[-1]
+    out_shape = (m, out_blk[1]) if variant in (NO_AGG, ROW_AGG) else out_blk
+    out_spec = pl.BlockSpec(out_blk, (lambda i: (i, 0))
+                            if variant in (NO_AGG, ROW_AGG)
+                            else (lambda i: (0, 0)))
 
     def kernel(*refs):
         *ins, out = refs

@@ -306,10 +306,12 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     from repro.configs import all_configs, cells
-    from repro.dist.compat import auto_axis_types
     probe_mesh = jax.make_mesh(
-        (4, 2), ("data", "model"), axis_types=auto_axis_types(2))
+        (4, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch, shape in cells(all_configs()):
         try:
             rec = probe_cell(arch, shape, probe_mesh, force=args.force)

@@ -37,6 +37,8 @@ def main() -> int:
     from repro.launch import ensure_host_device_count
     ensure_host_device_count(512)
 
+    from repro import compile_cache
+    compile_cache.enable()
     from repro.configs import all_configs, cells
     from repro.launch.dryrun_lib import run_cell
     from repro.launch.mesh import make_production_mesh

@@ -68,12 +68,8 @@ def collective_bytes(hlo_text: str) -> dict:
 # ---------------------------------------------------------------------------
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across JAX versions: older releases
-    return a one-element list of dicts, newer ones the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()``, or {} when the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def abstract_params(model: LM):

@@ -8,15 +8,14 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist.compat import auto_axis_types
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     import numpy as np
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)),
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
                          devices=jax.devices()[:n])
 
 

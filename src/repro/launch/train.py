@@ -176,6 +176,7 @@ def main() -> None:
     import argparse
     from dataclasses import replace
 
+    from repro import compile_cache
     from repro.checkpoint import CheckpointStore
     from repro.data import DataConfig, ShardedLoader
     from repro.dist import sharding as sh
@@ -194,6 +195,7 @@ def main() -> None:
     ap.add_argument("--fusion", default="off")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.configs import get_config
     cfg = get_config(args.arch)

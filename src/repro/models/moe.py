@@ -159,7 +159,6 @@ def moe_a2a(x: jnp.ndarray, p: dict, cfg: ModelConfig,
         return moe_capacity(x, p, cfg, capacity_factor)
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     has_w3 = "w3" in p
 
@@ -204,13 +203,13 @@ def moe_a2a(x: jnp.ndarray, p: dict, cfg: ModelConfig,
             aux = jax.lax.pmean(aux, a)
         return out.astype(x_loc.dtype), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(fsdp, None), P(None, None),
                   P(EP, None, None), P(EP, None, None),
                   P(EP, None, None)),
         out_specs=(P(fsdp, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"], p["w1"], p["w2"],
               p["w3"] if has_w3 else p["w1"])
 

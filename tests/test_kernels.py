@@ -281,3 +281,41 @@ def test_bcsr_exploit_path_parity(kind):
     exp = ref.execute_dense(cp, _dense_env(env))
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("dim,target,quantum,want", [
+    (784, 512, 128, 784),            # no 128-multiple divides 784: full width
+    (2_025_000, 256, 8, 216),        # 2^3·3^4·5^5: largest 8-multiple ≤ 256
+    (2_025_000, 128, 8, 120),
+    (65_536, 256, 8, 256),
+    (200, 256, 8, 200),              # fits the target: one full block
+    (1_000_003, 256, 8, 1_000_003),  # prime: only the whole axis is legal
+])
+def test_pick_block_is_tile_aligned(dim, target, quantum, want):
+    """Blocks are (8,128)-aligned divisors or the whole axis — the only
+    shapes Mosaic lowers."""
+    from repro.kernels.cellwise import pick_block
+    b = pick_block(dim, target, quantum)
+    assert b == want
+    assert dim % b == 0 and (b == dim or b % quantum == 0)
+
+
+def test_kernel_fallback_names_its_reason():
+    """A dense operator whose only legal block overflows VMEM (a prime
+    row count) takes its XLA body with a stated reason; an aligned one
+    takes its kernel; a dense Outer main says why it has none."""
+    from repro.kernels.ops import kernel_fallback
+
+    def expr(X, y):
+        return (ir.relu(X - 1.0) * y).sum()
+
+    for m, ok in ((2_025_000, True), (1_000_003, False)):
+        exprs = {"X": ir.matrix("X", (m, 784)), "y": ir.matrix("y", (m, 1))}
+        g = ir.Graph.build([expr(**exprs)])
+        ep = plan(g, "gen")
+        spec = next(s for s in ep.specs if getattr(s, "fused", True))
+        cp = build_cplan(g, spec)
+        reason = kernel_fallback(cp, {b.nid: b.shape for b in cp.binds})
+        assert (reason is None) == ok, reason
+        if not ok:
+            assert "VMEM" in reason and "(1000003, 784)" in reason
