@@ -77,17 +77,17 @@ def run(X, y, lam: float = 1e-3, max_iter: int = 20, eps: float = 1e-12,
             Xs = X @ s                        # basic GEMV
             out = _hinge(X, w, y)
             num_t, den_t = _search_terms(out, y * Xs)
-            num = fs(num_t) - lam * float(jnp.sum(w * s))
-            den = fs(den_t) + lam * float(jnp.sum(s * s))
+            num = fs(num_t) - lam * fs(jnp.sum(w * s))
+            den = fs(den_t) + lam * fs(jnp.sum(s * s))
             step = num / max(den, 1e-30)
             w = w + step * s
             val, g_new = obj_grad(w)          # fused forward + fused backward
-            objs.append(float(val))
-            beta = float(jnp.sum(g_new * g_new)) / max(
-                float(jnp.sum(g * g)), 1e-30)
+            objs.append(fs(val))
+            beta = fs(jnp.sum(g_new * g_new)) / max(
+                fs(jnp.sum(g * g)), 1e-30)
             s = -g_new + beta * s
             g = g_new
-            if float(jnp.sum(g * g)) < eps:
+            if fs(jnp.sum(g * g)) < eps:
                 break
     return w, objs
 

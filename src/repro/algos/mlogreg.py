@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .util import fs
 from repro.core import ir, fused, FusionContext
 
 
@@ -108,18 +109,18 @@ def run(X, Y, lam: float = 1e-3, max_outer: int = 10, max_inner: int = 20,
         for _ in range(max_outer):
             P = _probs(X, B)
             val, G = obj_grad(B)          # fused forward + fused backward
-            nlls.append(float(val))       # == NLL + 0.5·λ‖B‖² as before
+            nlls.append(fs(val))          # == NLL + 0.5·λ‖B‖² as before
             # CG solve (H + lam I) d = -G with fused HVPs
             d = jnp.zeros_like(B)
             r = -G
             p = r
-            rs = float(jnp.sum(r * r))
+            rs = fs(jnp.sum(r * r))
             for _ in range(max_inner):
                 Hp = _hvp(X, p, P) + lam * p
-                alpha = rs / max(float(jnp.sum(p * Hp)), 1e-30)
+                alpha = rs / max(fs(jnp.sum(p * Hp)), 1e-30)
                 d = d + alpha * p
                 r = r - alpha * Hp
-                rs_new = float(jnp.sum(r * r))
+                rs_new = fs(jnp.sum(r * r))
                 if rs_new < eps:
                     break
                 p = r + (rs_new / rs) * p

@@ -48,6 +48,7 @@ from typing import Callable, Optional
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.blocksparse import BCSR, DictCompressed
 from . import ir
 from .codegen import (CompiledPlan, compile_plan, freed_intermediates,
@@ -195,19 +196,23 @@ class Traced:
             ctx = ctx.with_(params=params)
         if layout is not None:
             ctx = ctx.with_(layout=layout)
-        if ctx.layout is not None and not isinstance(ctx.layout,
-                                                     FusionLayout):
-            # bare mesh (incl. via the scoped context): fit the sharding
-            # rules to this trace's operand and output shapes
-            shapes = {name: m["shape"] for name, m in self.in_meta.items()}
-            ctx = ctx.with_(layout=ensure_layout(ctx.layout, self.graph,
-                                                 extra_shapes=shapes))
-        eff = layout_cost_params(ctx.layout, self.graph, ctx.params)
-        eplan = plan_graph(self.graph, ctx.mode, eff)
-        rw_report = None
-        if ctx.rewrite:
-            eplan, rw_report = _rewrite_sweep(self.graph, ctx, eplan)
-        planned = _verified_planned(self, ctx, eplan)
+        with obs.span(obs.PLAN):
+            if ctx.layout is not None and not isinstance(ctx.layout,
+                                                         FusionLayout):
+                # bare mesh (incl. via the scoped context): fit the
+                # sharding rules to this trace's operand and output shapes
+                shapes = {name: m["shape"]
+                          for name, m in self.in_meta.items()}
+                ctx = ctx.with_(layout=ensure_layout(
+                    ctx.layout, self.graph, extra_shapes=shapes))
+            eff = layout_cost_params(ctx.layout, self.graph, ctx.params)
+            eplan = plan_graph(self.graph, ctx.mode, eff)
+            rw_report = None
+            if ctx.rewrite:
+                with obs.span(obs.PLAN_REWRITE):
+                    eplan, rw_report = _rewrite_sweep(self.graph, ctx,
+                                                      eplan)
+            planned = _verified_planned(self, ctx, eplan)
         planned._rewrite = rw_report
         return planned
 
@@ -279,9 +284,10 @@ def _verified_planned(traced: Traced, ctx: FusionContext,
     here — before any code generation can execute the broken plan."""
     planned = Planned(traced, ctx, eplan)
     if ctx.verify != "off":
-        report = verify_plan(eplan, level=ctx.verify, pallas=ctx.pallas,
-                             layout=ctx.layout)
-        report.raise_if_errors()
+        with obs.span(obs.PLAN_VERIFY):
+            report = verify_plan(eplan, level=ctx.verify,
+                                 pallas=ctx.pallas, layout=ctx.layout)
+            report.raise_if_errors()
         planned._verify = report
     return planned
 
@@ -361,7 +367,9 @@ class Planned:
         """Plan the gradient DAG through the same explore → select pipeline
         (fused backward operators).  Raises NonDifferentiableError when the
         forward graph has an op with no VJP rule."""
-        if self._bwd is None:
+        if self._bwd is not None:
+            return self._bwd
+        with obs.span(obs.PLAN):
             ct_names, grads = vjp_graph(self.eplan.graph)
             fwd_inputs = [n.name for n in self.eplan.graph.inputs()]
             bgraph = ir.Graph.build([grads[n] for n in fwd_inputs])
@@ -617,18 +625,19 @@ class Compiled:
             return run(*arrs), arrs          # residuals: primal inputs only
 
         def bwd(res, ct):
-            bwd_plan, grad_names, ct_names = self._get_bwd()
-            cts = ct if isinstance(ct, (tuple, list)) else (ct,)
-            binds = dict(zip(names, res))
-            binds.update({n: jnp.asarray(c, jnp.float32)
-                          for n, c in zip(ct_names, cts)})
-            grads = bwd_plan(binds)
-            if not isinstance(grads, tuple):
-                grads = (grads,)
-            by_name = dict(zip(grad_names, grads))
-            return tuple(by_name.get(n) if n in by_name
-                         else jnp.zeros_like(res[i])
-                         for i, n in enumerate(names))
+            with obs.span(obs.CALL):
+                bwd_plan, grad_names, ct_names = self._get_bwd()
+                cts = ct if isinstance(ct, (tuple, list)) else (ct,)
+                binds = dict(zip(names, res))
+                binds.update({n: jnp.asarray(c, jnp.float32)
+                              for n, c in zip(ct_names, cts)})
+                grads = bwd_plan(binds)
+                if not isinstance(grads, tuple):
+                    grads = (grads,)
+                by_name = dict(zip(grad_names, grads))
+                return tuple(by_name.get(n) if n in by_name
+                             else jnp.zeros_like(res[i])
+                             for i, n in enumerate(names))
 
         call.defvjp(fwd, bwd)
         return call
@@ -664,24 +673,25 @@ class Compiled:
         the direct dispatch path.  Any 1-D/0-D operand puts the call in
         "vector world": outputs round-trip back through
         :func:`_uncanon_output`."""
-        bound = self._bind(args, kwargs)
-        vector_world = any(
-            _canon_shape(n, v)[1] < 2 for n, v in bound.items())
-        canon = {n: _canon_value(n, v) for n, v in bound.items()}
-        dense = all(not isinstance(v, (BCSR, DictCompressed))
-                    for v in canon.values())
-        if dense:
-            if self._vjp_fn is None:
-                self._vjp_fn = self._build_vjp()
-            names = self.planned.traced.in_names
-            outs = self._vjp_fn(*[canon[n] for n in names])
-        else:
-            outs = self._run_plain(canon)
-        if vector_world:
-            if isinstance(outs, tuple):
-                return tuple(_uncanon_output(o) for o in outs)
-            return _uncanon_output(outs)
-        return outs
+        with obs.span(obs.CALL):
+            bound = self._bind(args, kwargs)
+            vector_world = any(
+                _canon_shape(n, v)[1] < 2 for n, v in bound.items())
+            canon = {n: _canon_value(n, v) for n, v in bound.items()}
+            dense = all(not isinstance(v, (BCSR, DictCompressed))
+                        for v in canon.values())
+            if dense:
+                if self._vjp_fn is None:
+                    self._vjp_fn = self._build_vjp()
+                names = self.planned.traced.in_names
+                outs = self._vjp_fn(*[canon[n] for n in names])
+            else:
+                outs = self._run_plain(canon)
+            if vector_world:
+                if isinstance(outs, tuple):
+                    return tuple(_uncanon_output(o) for o in outs)
+                return _uncanon_output(outs)
+            return outs
 
 
 # --------------------------------------------------------------------------
@@ -707,11 +717,12 @@ class Fused:
         ``.shape`` — arrays, ShapeDtypeStructs, BCSR — or python scalars)."""
         bound = dict(zip(self.names, args))
         bound.update(kwargs)
-        exprs = _as_expr_inputs(bound, self.sparsity)
-        outs = self.fn(**exprs)
-        if not isinstance(outs, (tuple, list)):
-            outs = (outs,)
-        graph = ir.Graph.build(list(outs))
+        with obs.span(obs.PLAN_TRACE):
+            exprs = _as_expr_inputs(bound, self.sparsity)
+            outs = self.fn(**exprs)
+            if not isinstance(outs, (tuple, list)):
+                outs = (outs,)
+            graph = ir.Graph.build(list(outs))
         meta = {}
         for name, v in bound.items():
             shape, _ = _canon_shape(name, v)

@@ -37,14 +37,14 @@ CLA-compressed); the full kernel-dispatch decision table lives in
 from __future__ import annotations
 
 import threading
-import time
 from collections import OrderedDict
 from dataclasses import dataclass, field, replace
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 
-from repro import faults
+from repro import faults, obs
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.blocksparse import (BCSR, DictCompressed, ShardedBCSR)
@@ -135,24 +135,25 @@ class PlanCache:
         """Returns (generated operator, this spec's CPlan).  The operator
         may come from a structurally-equal plan of a *different* graph, so
         callers bind inputs positionally via the returned CPlan."""
-        t0 = time.perf_counter()
-        cplan = build_cplan(graph, spec)
-        key = cplan.cache_key()
+        with obs.span(obs.CODEGEN) as sp:
+            cplan = build_cplan(graph, spec)
+            key = cplan.cache_key()
+            with self._lock:
+                hit = self._ops.get(key)
+                if hit is not None:
+                    self._ops.move_to_end(key)
+                    self.stats.hits += 1
+                    return hit, cplan
+                op = GeneratedOp(cplan)
+                self._ops[key] = op
+                while len(self._ops) > self.maxsize:
+                    self._ops.popitem(last=False)
+                    self.stats.evictions += 1
+                self.stats.size = len(self._ops)
         with self._lock:
-            hit = self._ops.get(key)
-            if hit is not None:
-                self._ops.move_to_end(key)
-                self.stats.hits += 1
-                return hit, cplan
-            op = GeneratedOp(cplan)
-            self._ops[key] = op
-            while len(self._ops) > self.maxsize:
-                self._ops.popitem(last=False)
-                self.stats.evictions += 1
             self.stats.misses += 1
-            self.stats.size = len(self._ops)
-            self.stats.codegen_time_s += time.perf_counter() - t0
-            return op, cplan
+            self.stats.codegen_time_s += sp.seconds
+        return op, cplan
 
     def __len__(self) -> int:
         with self._lock:
@@ -175,9 +176,10 @@ def plan_cache_stats() -> PlanCacheStats:
     (LRU past the configurable ``capacity`` bound — 512 operators by
     default), ``size`` (operators currently cached), ``capacity`` (the
     current LRU bound), and ``codegen_time_s`` (cumulative CPlan-build
-    time on misses).  The cache keys operators by *structural* CPlan
-    hash, so a hit means some structurally-equal plan — any expression,
-    any trace — already generated the operator.  Useful assertions:
+    time on misses, from their ``repro.codegen`` spans).  The cache keys
+    operators by *structural* CPlan hash, so a hit means some
+    structurally-equal plan — any expression, any trace — already
+    generated the operator.  Useful assertions:
     ``stats.total`` grows when a backward pass compiles, ``misses`` stays
     flat across re-traces of the same shapes."""
     with PLAN_CACHE._lock:
@@ -241,6 +243,8 @@ class WholePlanCache:
         self._lock = threading.RLock()
         self._pending: dict[tuple, threading.Event] = {}
         self._key_stats: "OrderedDict[str, dict]" = OrderedDict()
+        #: argument signatures each live staged function was called with
+        self._called: dict[tuple, set] = {}
         self.stats = WholePlanCacheStats(capacity=self.maxsize)
 
     # -- per-key metrics ----------------------------------------------------
@@ -286,6 +290,7 @@ class WholePlanCache:
         # caller holds the lock
         while len(self._fns) > self.maxsize:
             old_key, _ = self._fns.popitem(last=False)
+            self._called.pop(old_key, None)
             self.stats.evictions += 1
             self._key_record(old_key)["evictions"] += 1
 
@@ -301,6 +306,7 @@ class WholePlanCache:
     def put(self, key: tuple, fn: Callable, build_s: float) -> None:
         with self._lock:
             self._fns[key] = fn
+            self._called[key] = set()
             self._evict_over_capacity()
             self.stats.misses += 1
             self.stats.size = len(self._fns)
@@ -315,7 +321,8 @@ class WholePlanCache:
         to miss a key runs ``builder`` (outside the lock) while racing
         threads block on an in-flight event and then share the built
         function.  ``extra_build_s`` lets the caller account lowering
-        work done before the key existed (e.g. tracing the plan body)."""
+        work done before the key existed (e.g. tracing the plan body);
+        the build itself is timed by its ``repro.codegen`` span."""
         while True:
             with self._lock:
                 fn = self._fns.get(key)
@@ -330,19 +337,35 @@ class WholePlanCache:
                     self._pending[key] = ev
                     break                      # we own the build
             ev.wait()                          # another thread is building
-        t0 = time.perf_counter()
         try:
-            fn = builder()
-            self.put(key, fn, time.perf_counter() - t0 + extra_build_s)
+            with obs.span(obs.CODEGEN) as sp:
+                fn = builder()
+            self.put(key, fn, sp.seconds + extra_build_s)
             return fn
         finally:
             with self._lock:
                 self._pending.pop(key, None)
             ev.set()
 
+    def first_call(self, key: tuple, args) -> bool:
+        """True the first time the live staged function under ``key`` is
+        called with arguments of this signature (pytree structure, shapes
+        and dtypes): the call in which JAX traces, lowers and compiles
+        it.  False for a key no longer in the cache."""
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        sig = (tree, tuple((getattr(v, "shape", None),
+                            getattr(v, "dtype", None)) for v in leaves))
+        with self._lock:
+            seen = self._called.get(key)
+            if seen is None or sig in seen:
+                return False
+            seen.add(sig)
+            return True
+
     def clear(self) -> None:
         with self._lock:
             self._fns.clear()
+            self._called.clear()
             self._key_stats.clear()
             self.stats = WholePlanCacheStats(capacity=self.maxsize)
 
@@ -356,7 +379,8 @@ def whole_plan_cache_stats() -> WholePlanCacheStats:
     and with it the XLA executable), ``misses`` (staged functions built,
     concurrent builders coalesced to one build per key), ``size``,
     ``capacity`` (the configurable LRU bound), ``evictions``,
-    ``build_time_s`` (cumulative staged-lowering time on misses), and
+    ``build_time_s`` (cumulative staged-lowering time on misses, from
+    their ``repro.codegen`` spans), and
     ``tracked_keys``/``dropped_keys`` (per-key stat records alive /
     aged out — see :meth:`WholePlanCache.key_stats`)."""
     with WHOLE_PLAN_CACHE._lock:
@@ -391,7 +415,6 @@ class GeneratedOp:
             return self._run(env, pallas)     # validation path: stay eager
         fn = self._jits.get(pallas)
         if fn is None:
-            import jax
             fn = jax.jit(lambda e: self._run(e, pallas))
             self._jits[pallas] = fn
         return fn(env)
@@ -554,12 +577,26 @@ class CompiledPlan:
         return self._staged_fn, self._staged_raw
 
     def _build_staged(self) -> tuple[Callable, Callable]:
-        import jax
+        with obs.span(obs.CODEGEN) as sp:
+            key, plan_fn = self._lower_staged()
+        self._staged_key = key
+        # build-once under concurrency: racing threads compiling
+        # structurally-equal plans share one jitted function (and with
+        # it one XLA executable per shape signature)
+        def _build():
+            faults.fault_point("plan.jit_build")
+            return jax.jit(plan_fn)
+
+        jitted = WHOLE_PLAN_CACHE.get_or_create(
+            key, _build, extra_build_s=sp.seconds)
+        return jitted, plan_fn
+
+    def _lower_staged(self) -> tuple[tuple, Callable]:
+        """(structural whole-plan key, un-jitted plan function)."""
         from repro.kernels.distributed import (
             SegmentFallback, SegmentItem, lower_segment, plan_segment,
             run_segment_local)
 
-        t0 = time.perf_counter()
         graph, plan = self.plan.graph, self.plan
         specs = plan.specs
         in_nids = tuple(n.nid for n in graph.inputs())
@@ -723,17 +760,7 @@ class CompiledPlan:
 
         key = (tuple(key_parts), tuple(canon[o] for o in output_ids),
                self.pallas, tuple(getattr(self.plan, "rewrite", ()) or ()))
-        self._staged_key = key
-        # build-once under concurrency: racing threads compiling
-        # structurally-equal plans share one jitted function (and with
-        # it one XLA executable per shape signature)
-        def _build():
-            faults.fault_point("plan.jit_build")
-            return jax.jit(plan_fn)
-
-        jitted = WHOLE_PLAN_CACHE.get_or_create(
-            key, _build, extra_build_s=time.perf_counter() - t0)
-        return jitted, plan_fn
+        return key, plan_fn
 
     def batched_callable(self) -> Callable:
         """Jitted ``vmap`` of the staged whole-plan function over a new
@@ -750,7 +777,6 @@ class CompiledPlan:
             raise PlanInvariantError(
                 "batched_callable: batched (vmapped) execution requires "
                 "a mesh-free plan; this plan was compiled under a layout")
-        import jax
         _fn, raw = self.staged_callable()
         key = ("vmap", self._staged_key)
 
@@ -888,7 +914,12 @@ class CompiledPlan:
         fn, _raw = self.staged_callable()
         vals = {n.nid: bindings[n.name] for n in graph.inputs()}
         self._prepare_inputs(vals)
-        outs = fn(*[vals[n.nid] for n in graph.inputs()])
+        args = [vals[n.nid] for n in graph.inputs()]
+        if WHOLE_PLAN_CACHE.first_call(self._staged_key, args):
+            with obs.span(obs.STAGE):
+                outs = fn(*args)
+        else:
+            outs = fn(*args)
         return outs[0] if len(outs) == 1 else tuple(outs)
 
 

@@ -32,11 +32,9 @@ from .partitions import Partition, Point
 class EnumStats:
     partitions: int = 0
     points_total: int = 0
-    space_size: float = 0.0        # Σ 2^|M'_i| (unpruned space)
     plans_costed: int = 0
     plans_skipped_cost: float = 0.0
     plans_skipped_struct: float = 0.0
-    cut_sets_used: int = 0
 
 
 # -- reachability graph & cut sets -------------------------------------------
@@ -206,7 +204,6 @@ def mp_skip_enum(graph: Graph, memo: MemoTable, part: Partition,
             q = tuple(q)
             pskip = (1 << (n - len(cut.points_ix))) - 1
             st.plans_skipped_struct += pskip
-            st.cut_sets_used += 1
         banned = {pts[i] for i in range(n) if q[i]}
         # -- cost-based pruning (lines 11-15) ----------------------------------
         if use_cost_pruning and pskip == 0:

@@ -29,10 +29,7 @@ from .templates import TEMPLATES, Status, Template
 @dataclass
 class ExploreStats:
     operators: int = 0
-    entries_created: int = 0
     entries_kept: int = 0
-    opens: int = 0
-    fuses: int = 0
 
 
 def explore(graph: Graph, *, prune_dominated: bool = False,
@@ -70,16 +67,13 @@ def _ofmc_explore(h: Node, graph: Graph, memo: MemoTable,
     # -- open initial operator plans (lines 7-10) -----------------------------
     for t in TEMPLATES.values():
         if t.open(h):
-            st.opens += 1
             entries.extend(_create_plans(h, None, t, memo))
     # -- fuse and merge operator plans (lines 11-15) ---------------------------
     for j, gin in enumerate(h.inputs):
         for tt in memo.distinct_types(gin.nid):
             t = TEMPLATES[tt]
             if memo.has_open(gin.nid, tt) and t.fuse(h, gin):
-                st.fuses += 1
                 entries.extend(_create_plans(h, j, t, memo))
-    st.entries_created += len(entries)
 
     # -- close operator plans (lines 16-20) -------------------------------------
     kept: list[MemoEntry] = []

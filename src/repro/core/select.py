@@ -21,6 +21,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 from repro import hw as _hw
+from repro import obs
 from .cost import (CostParams, FusedOpSpec, Placement, TPU_V5E, node_bytes,
                    resolve_partition, row_partitioned, spec_cost,
                    spec_placement)
@@ -108,7 +109,6 @@ def select(graph: Graph, memo: MemoTable, mode: str = "gen",
     for part in parts:
         st.partitions += 1
         st.points_total += len(part.points)
-        st.space_size += 2 ** len(part.points)
         banned = _assignment(graph, memo, part, mode, params, st)
         probe = "greedy" if mode in ("fa", "fnr") else "cost"
         part_specs = resolve_partition(graph, memo, part, banned, params,
@@ -156,10 +156,12 @@ def plan(graph: Graph, mode: str = "gen", params: CostParams = TPU_V5E,
     else:
         ex_st = ExploreStats()
         dom = prune_dominated if prune_dominated is not None else mode in ("fa", "fnr")
-        memo = explore(graph, prune_dominated=dom, stats=ex_st)
+        with obs.span(obs.PLAN_EXPLORE):
+            memo = explore(graph, prune_dominated=dom, stats=ex_st)
     en_st = EnumStats()
-    specs, cost = select(graph, memo, mode, params, enum_stats=en_st)
-    segments = annotate_segments(graph, specs, params)
+    with obs.span(obs.PLAN_SELECT):
+        specs, cost = select(graph, memo, mode, params, enum_stats=en_st)
+        segments = annotate_segments(graph, specs, params)
     return ExecPlan(graph, specs, cost, memo, en_st, ex_st,
                     segments=segments, params=params)
 

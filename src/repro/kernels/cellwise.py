@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core.cplan import (CPlan, COL_AGG, FULL_AGG, NO_AGG, ROW_AGG)
 from . import ref
 
@@ -161,7 +162,8 @@ def cell_pallas(cplan: CPlan, env: dict[int, jnp.ndarray], *,
     out = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
-        interpret=interpret)(*arrays)
+        interpret=interpret,
+        name=obs.kernel_name("cell", variant, cplan.cache_key()))(*arrays)
     if agg == "mean":
         count = {ROW_AGG: n, COL_AGG: m, FULL_AGG: m * n}.get(variant, 1)
         out = out / count

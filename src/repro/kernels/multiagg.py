@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core.cplan import CPlan
 from . import ref
 from .cellwise import cell_blocks, _tile_spec, _COMB
@@ -59,7 +60,9 @@ def multiagg_pallas(cplan: CPlan, env: dict[int, jnp.ndarray], *,
         kernel, grid=(m // bm, n // bn), in_specs=in_specs,
         out_specs=pl.BlockSpec((k, 1), lambda o, i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, 1), dtype),
-        interpret=interpret)(*arrays)
+        interpret=interpret,
+        name=obs.kernel_name("magg", cplan.variant,
+                             cplan.cache_key()))(*arrays)
     scale = jnp.array([[1.0 / (m * n)] if a == "mean" else [1.0]
                        for a in aggs], dtype)
     return out * scale

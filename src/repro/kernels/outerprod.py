@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core.cplan import (CPlan, FULL_AGG, NO_AGG, RIGHT_MM)
 from . import ref
 from .blocksparse import BCSR
@@ -165,7 +166,10 @@ def outer_pallas(cplan: CPlan, env: dict[int, object], *,
         aliases = {len(args) + 1: 0}      # +2 scalar-prefetch operands
     out = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
                          input_output_aliases=aliases,
-                         interpret=interpret)(X.rows, X.cols, *args)
+                         interpret=interpret,
+                         name=obs.kernel_name("outer", variant,
+                                              cplan.cache_key()))(
+        X.rows, X.cols, *args)
     if variant == NO_AGG:
         return BCSR(out, X.rows, X.cols, X.shape, bs)
     return out

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core.cplan import (CPlan, COL_AGG, COL_T_AGG, FULL_AGG, NO_AGG,
                               ROW_AGG)
 from . import ref
@@ -100,7 +101,8 @@ def row_pallas(cplan: CPlan, env: dict[int, jnp.ndarray], *,
     out = pl.pallas_call(
         kernel, grid=(m // bm,), in_specs=in_specs, out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
-        interpret=interpret)(*arrays)
+        interpret=interpret,
+        name=obs.kernel_name("row", variant, cplan.cache_key()))(*arrays)
     if agg == "mean" and variant in (ROW_AGG, COL_AGG, FULL_AGG):
         rr, rc = _root_shape(cplan)
         count = {ROW_AGG: rc, COL_AGG: rr, FULL_AGG: rr * rc}[variant]

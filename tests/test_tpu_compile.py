@@ -10,12 +10,14 @@ runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.core import FusionContext, fused, ir
 from repro.core.codegen import PLAN_CACHE
 from repro.core.cost import FusedOpSpec
@@ -121,3 +123,19 @@ def test_outer_right_mm_compiles(one_chip, m):
     assert kinds == [("OUTER", "right_mm", False)]
     assert fbs == []
     assert "tpu_custom_call" in hlo
+
+
+def test_kernel_name_in_the_hlo(one_chip):
+    """The Row kernel's name (``repro.obs.kernel_name``) names its
+    custom-call instruction in the compiled HLO, which is the op name a
+    TPU trace reports.  The row count is this test's own, so the kernel
+    is lowered, and named, here."""
+    m = 8192
+    region = fused(lambda X, v, P: X.T @ (P * (X @ v)))
+    before = obs.kernel_names()
+    kinds, _fbs, hlo = _compile_tpu(
+        region, (_S(m, N), _S(N, 10), _S(m, 10)), one_chip)
+    (name,) = obs.kernel_names() - before
+    assert kinds == [("ROW", "col_t_agg", False)]
+    assert name.startswith("row_col_t_agg_")
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", hlo)
