@@ -126,7 +126,7 @@ def compile_programs(m: int, pallas: str, mesh=None) -> list[dict]:
                         sharding=one if mesh is None else NamedSharding(
                             mesh, specs.get(nd.name) or P()))
                     for nd in cp.plan.graph.inputs()]
-            fn, raw = cp.staged_callable()
+            fn, raw = cp.staged_callable(args)
             progs.append({"label": name, "planned": pl, "cplan": cp,
                           "raw": raw, "args": args,
                           "lowered": fn.lower(*args)})

@@ -52,7 +52,7 @@ from repro import obs
 from repro.kernels.blocksparse import BCSR, DictCompressed
 from . import ir
 from .codegen import (CompiledPlan, compile_plan, freed_intermediates,
-                      plan_fallbacks)
+                      plan_fallbacks, row_orientations)
 from .context import FusionContext, current_context
 from .cost import CostParams
 from .grad import vjp_graph
@@ -456,6 +456,13 @@ class Planned:
             },
             "layout": None,
         }
+        if self.context.pallas != "never" and self.context.staged:
+            # each Row kernel's orientation (row- or lane-major), decided
+            # per call by its main's device layout; Compiled.explain()
+            # puts in what the last call chose
+            report["execution"]["row_orientation"] = row_orientations(
+                self.eplan, pallas=self.context.pallas,
+                layout=self.context.layout)
         if self._verify is None and self.context.verify != "off":
             self._verify = verify_plan(self.eplan,
                                        level=self.context.verify,
@@ -652,6 +659,11 @@ class Compiled:
         for f in self._cplan.fallbacks:
             if (f["site"], f["reason"]) not in seen:
                 static.append(dict(f))
+        rows = report["execution"].get("row_orientation")
+        if rows is not None:        # what the last call chose, per kernel
+            chosen = {e["specs"][0]: e for e in self._cplan.row_orientation}
+            report["execution"]["row_orientation"] = [
+                chosen.get(e["specs"][0], e) for e in rows]
         bwd = self._bwd_compiled
         if bwd is not None:
             seen = {(f["site"], f["reason"]) for f in static}

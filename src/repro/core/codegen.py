@@ -36,6 +36,7 @@ CLA-compressed); the full kernel-dispatch decision table lives in
 
 from __future__ import annotations
 
+import functools
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field, replace
@@ -43,16 +44,20 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout
 
 from repro import faults, obs
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.blocksparse import (BCSR, DictCompressed, ShardedBCSR)
+from repro.kernels.rowwise import lane_forms
 from .cost import FusedOpSpec
 from .cplan import CPlan, NO_AGG, build_cplan
 from .ir import Graph, Node
 from .partitions import PlanInvariantError
 from .select import ExecPlan, MultiAggSpec
+from .templates import TType
 
 
 faults.register_site(
@@ -533,6 +538,15 @@ class CompiledPlan:
     _staged_raw: Optional[Callable] = field(default=None, repr=False)
     #: structural whole-plan cache key of the staged lowering
     _staged_key: Optional[tuple] = field(default=None, repr=False)
+    #: lane-major staged lowerings: frozenset of the input positions
+    #: whose Row kernels run lane-major -> (jitted, raw, key)
+    _staged_lanes: dict = field(default_factory=dict, repr=False)
+    #: input position -> spec indices of the local Row kernels it is the
+    #: main of and that have a lane-major form; and every local Row
+    #: kernel's orientation entry (set by the staged lowering, updated
+    #: per call: ``explain()["execution"]["row_orientation"]``)
+    _lane_mains: dict = field(default_factory=dict, repr=False)
+    _row_orient: dict = field(default_factory=dict, repr=False)
     #: mesh-validated SegmentPlans of the staged lowering (real mesh)
     _seg_plans: list = field(default_factory=list, repr=False)
     #: recorded execution downgrades, deduped by (site, reason, specs)
@@ -566,20 +580,50 @@ class CompiledPlan:
 
     # -- staged whole-plan path --------------------------------------------
 
-    def staged_callable(self) -> tuple[Callable, Callable]:
+    def staged_callable(self, args=None) -> tuple[Callable, Callable]:
         """(jitted whole-plan function, its un-jitted trace function),
         building them on first use.  Both take the graph's input arrays
         positionally (``graph.inputs()`` order) and return the tuple of
         graph outputs; the raw function is exposed so tests can inspect
-        the plan's jaxpr (e.g. count ``shard_map`` regions)."""
-        if self._staged_fn is None:
-            self._staged_fn, self._staged_raw = self._build_staged()
-        return self._staged_fn, self._staged_raw
+        the plan's jaxpr (e.g. count ``shard_map`` regions).
 
-    def _build_staged(self) -> tuple[Callable, Callable]:
+        ``args`` (arrays or ``jax.ShapeDtypeStruct`` in that order)
+        selects the Row kernels' orientation from the operands' device
+        layouts (:meth:`lane_inputs`); without it every Row kernel is
+        row-major."""
+        lanes = frozenset() if args is None else self.lane_inputs(args)
+        fn, raw, _key = self._staged_for(lanes)
+        return fn, raw
+
+    def _staged_for(self, lanes: frozenset) -> tuple[Callable, Callable,
+                                                     tuple]:
+        """(jitted, raw, whole-plan key) of the staged lowering whose Row
+        kernels over the inputs at ``lanes`` run lane-major, built on
+        first use; the row-major lowering is always built first."""
+        if self._staged_fn is None:
+            self._staged_fn, self._staged_raw, self._staged_key = \
+                self._build_staged()
+        if not lanes:
+            return self._staged_fn, self._staged_raw, self._staged_key
+        hit = self._staged_lanes.get(lanes)
+        if hit is None:
+            hit = self._staged_lanes[lanes] = self._build_staged(lanes)
+        return hit
+
+    def lane_inputs(self, args) -> frozenset:
+        """Positions of the inputs in ``args`` whose Row kernels run
+        lane-major: the input is the main of a local Row kernel that has
+        a lane-major form, and its device stores it column-major
+        (:func:`device_layout` (1, 0)), as a TPU stores an (m, n) f32
+        array whose n is not a multiple of 128."""
+        self._staged_for(frozenset())
+        return frozenset(p for p in self._lane_mains
+                         if device_layout(args[p]) == (1, 0))
+
+    def _build_staged(self, lanes: frozenset = frozenset()
+                      ) -> tuple[Callable, Callable, tuple]:
         with obs.span(obs.CODEGEN) as sp:
-            key, plan_fn = self._lower_staged()
-        self._staged_key = key
+            key, plan_fn = self._lower_staged(lanes)
         # build-once under concurrency: racing threads compiling
         # structurally-equal plans share one jitted function (and with
         # it one XLA executable per shape signature)
@@ -589,10 +633,13 @@ class CompiledPlan:
 
         jitted = WHOLE_PLAN_CACHE.get_or_create(
             key, _build, extra_build_s=sp.seconds)
-        return jitted, plan_fn
+        return jitted, plan_fn, key
 
-    def _lower_staged(self) -> tuple[tuple, Callable]:
-        """(structural whole-plan key, un-jitted plan function)."""
+    def _lower_staged(self, lanes: frozenset = frozenset()
+                      ) -> tuple[tuple, Callable]:
+        """(structural whole-plan key, un-jitted plan function).  Row
+        kernels whose main is the input at a position in ``lanes`` lower
+        lane-major; the key then names those positions."""
         from repro.kernels.distributed import (
             SegmentFallback, SegmentItem, lower_segment, plan_segment,
             run_segment_local)
@@ -618,6 +665,9 @@ class CompiledPlan:
         key_parts: list[tuple] = []      # structural key, one per step
         spec_step: dict[int, int] = {}   # spec idx -> step idx
         self._seg_plans = []
+        in_pos = {nid: p for p, nid in enumerate(in_nids)}
+        lane_mains: dict[int, list[int]] = {}
+        row_orient: dict[int, dict] = {}
 
         def _token(roots: tuple[int, ...], step_idx: int,
                    item_idx: int = 0) -> None:
@@ -684,7 +734,14 @@ class CompiledPlan:
                     key_parts.append(_seg_key(items, sp))
                     self._seg_plans.append(sp)
                 else:
-                    steps.append(("fused", cplan, bind_nids, roots))
+                    entry, main_pos = _row_entry(idx, cplan, in_pos,
+                                                 self.pallas)
+                    if entry is not None:
+                        row_orient[idx] = entry
+                    if main_pos is not None:
+                        lane_mains.setdefault(main_pos, []).append(idx)
+                    steps.append(("fused", cplan, bind_nids, roots,
+                                  main_pos in lanes))
                     key_parts.append((
                         "fused", cplan.cache_key(),
                         tuple(canon[nid] for nid in bind_nids)))
@@ -742,10 +799,10 @@ class CompiledPlan:
                         else:
                             env[roots[0]] = out
                 elif kind == "fused":
-                    _, cplan, bind_nids, roots = step
+                    _, cplan, bind_nids, roots, lane_major = step
                     out = kops.execute(
                         cplan, {nid: _mat(env[nid]) for nid in bind_nids},
-                        pallas=pallas)
+                        pallas=pallas, lanes=lane_major)
                     if len(roots) > 1:
                         for k, r in enumerate(roots):
                             env[r] = out[k].reshape(1, 1)
@@ -760,7 +817,37 @@ class CompiledPlan:
 
         key = (tuple(key_parts), tuple(canon[o] for o in output_ids),
                self.pallas, tuple(getattr(self.plan, "rewrite", ()) or ()))
+        if lanes:
+            key += (("rowt", tuple(sorted(lanes))),)
+        else:
+            self._lane_mains, self._row_orient = lane_mains, row_orient
         return key, plan_fn
+
+    @property
+    def row_orientation(self) -> list:
+        """Each local Row kernel's orientation, as the last call chose
+        it, with the reason where it is row-major."""
+        return [dict(e) for _i, e in sorted(self._row_orient.items())]
+
+    def _orient_rows(self, args) -> frozenset:
+        """The input positions whose Row kernels run lane-major in a
+        call with ``args`` (:meth:`lane_inputs`); records each Row
+        kernel's orientation."""
+        lanes = set()
+        for p, idxs in self._lane_mains.items():
+            layout = device_layout(args[p])
+            if layout == (1, 0):
+                lanes.add(p)
+            for idx in idxs:
+                e = self._row_orient[idx] = {"specs": [idx]}
+                e["orientation"] = "lane_major" if p in lanes else "row_major"
+                if layout is None:
+                    e["reason"] = (f"the main's device layout is not "
+                                   f"readable (input {p})")
+                elif p not in lanes:
+                    e["reason"] = (f"the main is stored major_to_minor "
+                                   f"{layout} (input {p})")
+        return frozenset(lanes)
 
     def batched_callable(self) -> Callable:
         """Jitted ``vmap`` of the staged whole-plan function over a new
@@ -911,16 +998,103 @@ class CompiledPlan:
                 raise KeyError(f"missing binding for input '{node.name}'")
         if not self.staged:
             return self._call_per_op(bindings)
-        fn, _raw = self.staged_callable()
+        self._staged_for(frozenset())    # the lowering preflight reads
         vals = {n.nid: bindings[n.name] for n in graph.inputs()}
         self._prepare_inputs(vals)
         args = [vals[n.nid] for n in graph.inputs()]
-        if WHOLE_PLAN_CACHE.first_call(self._staged_key, args):
+        fn, _raw, key = self._staged_for(self._orient_rows(args))
+        if WHOLE_PLAN_CACHE.first_call(key, args):
             with obs.span(obs.STAGE):
                 outs = fn(*args)
         else:
             outs = fn(*args)
         return outs[0] if len(outs) == 1 else tuple(outs)
+
+
+
+
+def _row_entry(idx: int, cplan: CPlan, in_pos: dict,
+               pallas: str) -> tuple[Optional[dict], Optional[int]]:
+    """(orientation entry, input position of the main where the kernel
+    may run lane-major) of the local fused operator ``idx``; (None,
+    None) where it is no dense Row kernel."""
+    if pallas == "never" or cplan.ttype != TType.ROW or cplan.extra:
+        return None, None
+    shapes = {b.nid: tuple(b.shape) for b in cplan.binds}
+    if kops.kernel_fallback(cplan, shapes) is not None:
+        return None, None               # XLA body in either orientation
+    reason = (lane_forms(cplan)[1]
+              or kops.kernel_fallback(cplan, shapes, lanes=True))
+    if reason is None and cplan.main.nid not in in_pos:
+        reason = (f"main %{cplan.main.nid} is computed inside the plan: "
+                  f"its device layout is not observed")
+    if reason is not None:
+        return {"specs": [idx], "orientation": "row_major",
+                "reason": reason}, None
+    return ({"specs": [idx], "orientation": "by_layout"},
+            in_pos[cplan.main.nid])
+
+
+def row_orientations(plan: ExecPlan, pallas: str = "never", layout=None,
+                     cache: Optional[PlanCache] = None) -> list:
+    """The static part of ``explain()["execution"]["row_orientation"]``:
+    one entry per local dense Row kernel, row-major with the reason, or
+    ``by_layout`` where each call's main decides (lane-major where the
+    device stores it column-major).  Operators a
+    mesh runs inside ``shard_map`` keep the row-major lowering and are
+    not listed."""
+    cache = cache if cache is not None else PLAN_CACHE
+    graph = plan.graph
+    in_pos = {n.nid: p for p, n in enumerate(graph.inputs())}
+    sharded = set()
+    if _mesh_of(layout) is not None:
+        sharded = {j for seg in plan.segments for j in seg.indices}
+        sharded |= {j for j, s in enumerate(plan.specs)
+                    if getattr(getattr(s, "placement", None), "arm",
+                               None) == "distributed"}
+    out = []
+    for idx, spec in enumerate(plan.specs):
+        if idx in sharded or not (isinstance(spec, MultiAggSpec) or (
+                isinstance(spec, FusedOpSpec) and spec.fused)):
+            continue
+        _op, cplan = cache.get_or_build(graph, spec)
+        entry, _pos = _row_entry(idx, cplan, in_pos, pallas)
+        if entry is not None:
+            out.append(entry)
+    return out
+
+
+def device_layout(v) -> Optional[tuple]:
+    """The major-to-minor order in which the device stores the 2-D
+    array ``v``, or None where it cannot be read: a tracer, a sparse or
+    compressed operand, an array on more than one device.  A host array
+    and a ``jax.ShapeDtypeStruct`` (an AOT compile) without a layout of
+    its own read the default layout for their shape and dtype of the
+    device they go to (the default device for a host array)."""
+    if isinstance(v, jax.core.Tracer) or getattr(v, "ndim", 0) != 2:
+        return None
+    try:
+        if isinstance(v, jax.Array):
+            if len(v.sharding.device_set) != 1:
+                return None
+            return v.format.layout.major_to_minor
+        if isinstance(v, jax.ShapeDtypeStruct):
+            if v.format.layout is not None:
+                return v.format.layout.major_to_minor
+            (dev,) = v.sharding.device_set
+        elif isinstance(v, np.ndarray):
+            dev = jax.config.jax_default_device or jax.devices()[0]
+        else:
+            return None
+        return _default_layout(dev, tuple(v.shape), np.dtype(v.dtype))
+    except Exception:                 # noqa: BLE001 — unreadable layout
+        return None
+
+
+@functools.lru_cache(maxsize=256)
+def _default_layout(dev, shape: tuple, dtype) -> tuple:
+    return Layout.from_pjrt_layout(dev.client.get_default_layout(
+        dtype, shape, dev)).major_to_minor
 
 
 def _last_uses(plan: ExecPlan) -> dict[int, list[int]]:

@@ -19,6 +19,7 @@ from jax.experimental import pallas as pl
 
 from repro import obs
 from repro.core.cplan import (CPlan, COL_AGG, FULL_AGG, NO_AGG, ROW_AGG)
+from repro.hw import TPU_V5E
 from . import ref
 
 
@@ -28,6 +29,9 @@ from . import ref
 SUBLANE, LANE = 8, 128
 #: tile targets (rows, cols) of the Cell and MAgg skeletons
 CELL_TILE = (256, 512)
+#: scoped VMEM the pipelined blocks of one kernel may fill: half of the
+#: chip's scoped limit, the program's tile temporaries get the rest
+VMEM_BLOCK_BUDGET = TPU_V5E.vmem_scoped_bytes // 2
 
 
 def pick_block(dim: int, target: int, quantum: int) -> int:

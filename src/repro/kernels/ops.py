@@ -49,8 +49,11 @@ faults.register_site(
 # --------------------------------------------------------------------------
 
 def execute(cplan: CPlan, env: dict[int, object], *,
-            pallas: str = "never") -> jnp.ndarray:
+            pallas: str = "never", lanes: bool = False) -> jnp.ndarray:
     """Run one fused operator.  ``pallas`` ∈ {"never","interpret","tpu"}.
+    ``lanes`` lowers a dense Row kernel in its lane-major orientation
+    (rows on lanes, :func:`repro.kernels.rowwise.row_pallas`); the
+    staged plan sets it where the main's device layout is column-major.
 
     Inside a ``shard_map`` body the operands are the shard-local panels,
     so the Pallas template lowerings derive their grids and BlockSpecs
@@ -81,25 +84,30 @@ def execute(cplan: CPlan, env: dict[int, object], *,
         env[cplan.main.nid] = main.todense()   # not exploitable: decompress
     env = {k: (v.todense() if isinstance(v, (BCSR, DictCompressed)) else v)
            for k, v in env.items()}
+    lanes = lanes and cplan.ttype == TType.ROW and not cplan.extra
     if pallas != "never" and kernel_fallback(
-            cplan, {b.nid: env[b.nid].shape for b in cplan.binds}) is None:
+            cplan, {b.nid: env[b.nid].shape for b in cplan.binds},
+            lanes=lanes) is None:
         from . import cellwise, multiagg, rowwise
         interpret = pallas == "interpret"
         if cplan.extra:
             return multiagg.multiagg_pallas(cplan, env, interpret=interpret)
         if cplan.ttype == TType.ROW:
-            return rowwise.row_pallas(cplan, env, interpret=interpret)
+            return rowwise.row_pallas(cplan, env, interpret=interpret,
+                                      lanes=lanes)
         return cellwise.cell_pallas(cplan, env, interpret=interpret)
     return ref.execute_dense(cplan, env)
 
 
-def kernel_fallback(cplan: CPlan, shapes: dict) -> Optional[str]:
+def kernel_fallback(cplan: CPlan, shapes: dict,
+                    lanes: bool = False) -> Optional[str]:
     """Why a dense fused operator takes its XLA body instead of its Pallas
     kernel at these operand shapes (nid -> (rows, cols)), or None when
     the kernel applies.  The pipelined blocks may fill half of the
-    chip's scoped VMEM; the program's tile temporaries get the rest."""
-    from repro.hw import TPU_V5E
-    from .cellwise import cell_blocks, vmem_bytes
+    chip's scoped VMEM; the program's tile temporaries get the rest.
+    ``lanes`` reckons a Row kernel's blocks in its lane-major
+    orientation (:mod:`repro.kernels.rowwise`)."""
+    from .cellwise import VMEM_BLOCK_BUDGET, cell_blocks, vmem_bytes
     from .rowwise import row_blocks
     if cplan.ttype == TType.OUTER:
         return ("Outer template over a dense main: the Outer kernel is "
@@ -107,8 +115,8 @@ def kernel_fallback(cplan: CPlan, shapes: dict) -> Optional[str]:
     if cplan.extra or cplan.ttype in (TType.CELL, TType.MAGG):
         blocks = cell_blocks(cplan, shapes)[2]
     else:
-        blocks = row_blocks(cplan, shapes)[1]
-    need, budget = vmem_bytes(blocks), TPU_V5E.vmem_scoped_bytes // 2
+        blocks = row_blocks(cplan, shapes, lanes=lanes)[1]
+    need, budget = vmem_bytes(blocks), VMEM_BLOCK_BUDGET
     if need > budget:
         return (f"{cplan.ttype.name} blocks {blocks} need {need} B of VMEM "
                 f"> {budget} B: no (8,128)-aligned block divides "

@@ -31,9 +31,15 @@ Span names, all under :data:`PREFIX`:
   dispatch in the custom VJP);
 * ``repro.sync``: a blocking device-to-host read of the fit loops.
 
+Counts (:func:`count`) share the table, with no seconds:
+
+* ``repro.kernel.row_lanes``: a Row kernel lowered in its lane-major
+  orientation (:mod:`repro.kernels.rowwise`), once per lowering.
+
 Kernel names (:func:`kernel_name`) have the form
 ``<template>_<variant>_<plan digest>`` and are given to every
-``pallas_call``, so a device trace names each generated kernel.
+``pallas_call``, so a device trace names each generated kernel; a
+lane-major Row kernel's template token is ``rowt``.
 """
 
 from __future__ import annotations
@@ -54,6 +60,7 @@ CODEGEN = "repro.codegen"
 STAGE = "repro.stage"
 CALL = "repro.call"
 SYNC = "repro.sync"
+ROW_LANES = "repro.kernel.row_lanes"
 
 _lock = threading.Lock()
 _table: dict[str, list] = {}          # name -> [seconds, count]
@@ -97,6 +104,16 @@ class span:
             else:
                 rec[0] += self.seconds
                 rec[1] += 1
+
+
+def count(name: str) -> None:
+    """Add one to the count of ``name`` in the table (no seconds)."""
+    with _lock:
+        rec = _table.get(name)
+        if rec is None:
+            _table[name] = [0.0, 1]
+        else:
+            rec[1] += 1
 
 
 def snapshot() -> dict[str, dict]:

@@ -137,6 +137,85 @@ def test_row_kernel_variants(variant):
                                np.asarray(exp), rtol=1e-3, atol=1e-3)
 
 
+def _check_lanes(cp, env):
+    """The lane-major lowering of a Row operator against the oracle and
+    against the row-major lowering."""
+    dense = _dense_env(env)
+    exp = np.asarray(ref.execute_dense(cp, dense))
+    got = np.asarray(row_pallas(cp, dense, interpret=True, lanes=True))
+    rows = np.asarray(row_pallas(cp, dense, interpret=True))
+    atol = 1e-4 * max(1.0, float(np.abs(exp).max()))
+    assert got.shape == exp.shape
+    np.testing.assert_allclose(got, exp, rtol=1e-3, atol=atol)
+    np.testing.assert_allclose(got, rows.reshape(exp.shape), rtol=1e-3,
+                               atol=atol)
+
+
+@pytest.mark.parametrize("m", [32, 100, 256])
+@pytest.mark.parametrize("k", [1, 4, 16])
+def test_row_kernel_mmchain_sweep_lane_major(m, k):
+    X = jnp.asarray(rng.normal(size=(m, 24)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(24, k)), jnp.float32)
+    cp, env = _fused_cplan(lambda X, v: X.T @ (X @ v), dict(X=X, v=v))
+    assert cp.variant == "col_t_agg"
+    _check_lanes(cp, env)
+
+
+_LANE_VARIANTS = {
+    "rowsum_chain": ("row_agg", lambda X, v, r: ((X @ v) * 2.0).rowsums()),
+    "full": ("full_agg", lambda X, v, r: ((X @ v) ** 2).sum()),
+    "noagg": ("no_agg", lambda X, v, r: (X @ v) * (X @ v).rowsums()),
+    "colsum": ("col_agg", lambda X, v, r: (X * (X @ v).rowsums()).colsums()),
+    "col_t": ("col_t_agg", lambda X, v, r: X.T @ (X @ v)),
+    "rowvec": ("row_agg", lambda X, v, r: (X * r).rowsums()),
+}
+
+
+@pytest.mark.parametrize("shape", [(64, 20, 3), (2048, 784, 1),
+                                   (2048, 784, 10)])
+@pytest.mark.parametrize("variant", sorted(_LANE_VARIANTS))
+def test_row_kernel_variants_lane_major(variant, shape):
+    """Every Row variant lowered lane-major, at a toy width and at
+    Mnist8m's 784 columns (not a multiple of 128) over two grid steps,
+    with one column (the sublane-reduce product) and with ten (MXU)."""
+    from repro.kernels.rowwise import row_blocks
+    m, n, k = shape
+    X = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)
+    r = jnp.asarray(rng.normal(size=(1, n)), jnp.float32)
+    want, expr = _LANE_VARIANTS[variant]
+    cp, env = _fused_cplan(lambda X, v, r: expr(X, v, r), dict(X=X, v=v, r=r))
+    assert cp.variant == want
+    tm = row_blocks(cp, {b: a.shape for b, a in env.items()}, lanes=True)[0]
+    assert m // tm == (2 if n == 784 else 1)
+    _check_lanes(cp, env)
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_row_kernel_lane_major_vector_main(which):
+    """The planned L2SVM backward's Row operators, whose main is the
+    (m, 1) label vector, lane-major over two grid steps."""
+    from repro.algos import l2svm
+    from repro.core.codegen import PLAN_CACHE
+    from repro.core.templates import TType
+    from repro.kernels.rowwise import row_blocks
+    m, n = 16384, 8
+    S = lambda *s: np.zeros(s, np.float32)
+    bwd = l2svm._objective_full.trace(S(m, n), S(n, 1), S(m, 1),
+                                      S(1, 1)).plan(mode="gen").backward()
+    g = bwd.eplan.graph
+    rows = [cp for cp in (PLAN_CACHE.get_or_build(g, s)[1]
+                          for s in bwd.eplan.specs if getattr(s, "fused", 0))
+            if cp.ttype == TType.ROW]
+    cp = rows[which]
+    assert cp.main.shape == (m, 1)
+    env = {b.nid: jnp.asarray(rng.normal(size=b.shape), jnp.float32)
+           for b in cp.binds}
+    tm = row_blocks(cp, {b: a.shape for b, a in env.items()}, lanes=True)[0]
+    assert m // tm == 2
+    _check_lanes(cp, env)
+
+
 def _random_bcsr(mb, nb, bs, density, rng):
     mask = rng.random((mb, nb)) < density
     mask.flat[0] = True
@@ -245,6 +324,14 @@ def test_row_parity_kinds(kind):
     exp = ref.execute_dense(cp, _dense_env(env))
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("kind", PARITY_KINDS)
+def test_row_parity_kinds_lane_major(kind):
+    X, Xd = _parity_matrix(kind)
+    v = jnp.asarray(rng.normal(size=(Xd.shape[1], 4)), jnp.float32)
+    cp, env = _fused_cplan(lambda X, v: X.T @ (X @ v), dict(X=X, v=v))
+    _check_lanes(cp, env)
 
 
 @pytest.mark.parametrize("kind", PARITY_KINDS)

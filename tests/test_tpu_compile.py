@@ -139,3 +139,41 @@ def test_kernel_name_in_the_hlo(one_chip):
     assert kinds == [("ROW", "col_t_agg", False)]
     assert name.startswith("row_col_t_agg_")
     assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", hlo)
+
+
+#: one chip's rows of the benchmark's L2SVM cell
+CELL_ROWS = 1_011_712
+
+
+@pytest.mark.parametrize("program", ["hinge", "objective", "objective.grad"])
+def test_l2svm_programs_read_x_in_place(one_chip, program):
+    """The described v5e stores X, f32[1011712, 784], and the (m, 1)
+    vectors column-major by default, so the L2SVM programs' Row kernels
+    lower lane-major and name themselves ``rowt_*``: the compiled HLO
+    has no copy or transpose that produces an f32[1011712,784] value,
+    and the forward programs' kernels read X through a bitcast (the
+    backward's Row kernels are over the (m, 1) label vector)."""
+    from repro.algos import l2svm
+    m = CELL_ROWS
+    region, shapes = {
+        "hinge": (l2svm._hinge, ((m, N), (N, 1), (m, 1))),
+        "objective": (l2svm._objective_full, ((m, N), (N, 1), (m, 1), (1, 1))),
+        "objective.grad": (l2svm._objective_full,
+                           ((m, N), (N, 1), (m, 1), (1, 1))),
+    }[program]
+    compiled = region.trace(*[_S(*s) for s in shapes]).plan(
+        context=FusionContext(pallas="tpu")).compile()
+    cplan = (compiled._get_bwd()[0] if program.endswith(".grad")
+             else compiled._cplan)
+    args = [_sds(tuple(nd.shape), one_chip)
+            for nd in cplan.plan.graph.inputs()]
+    assert cplan.lane_inputs(args), "no column-major Row main"
+    _fn, raw = cplan.staged_callable(args)
+    hlo = jax.jit(raw).lower(*args).compile().as_text()
+    x = rf"f32\[{m},{N}\]\{{[^}}]*\}}"
+    assert not re.search(rf"= {x} (copy|transpose)\(", hlo)
+    if not program.endswith(".grad"):
+        assert re.search(rf"= f32\[{N},{m}\]\{{[^}}]*\}} bitcast\(", hlo)
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = \S+ custom-call\(", hlo)
+    rows = [k for k in kernels if k.startswith(("row_", "rowt_"))]
+    assert rows and all(k.startswith("rowt_") for k in rows), kernels
