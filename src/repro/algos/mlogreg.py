@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from .util import fs
+from repro import obs
 from repro.core import ir, fused, FusionContext
 
 
@@ -108,7 +109,8 @@ def run(X, Y, lam: float = 1e-3, max_outer: int = 10, max_inner: int = 20,
             lambda B_: _nll_obj_reg(X, B_, Y, lam_s)[0, 0])
         for _ in range(max_outer):
             P = _probs(X, B)
-            val, G = obj_grad(B)          # fused forward + fused backward
+            with obs.span(obs.GRAD):      # fused forward + fused backward
+                val, G = obj_grad(B)
             nlls.append(fs(val))          # == NLL + 0.5·λ‖B‖² as before
             # CG solve (H + lam I) d = -G with fused HVPs
             d = jnp.zeros_like(B)
@@ -116,15 +118,16 @@ def run(X, Y, lam: float = 1e-3, max_outer: int = 10, max_inner: int = 20,
             p = r
             rs = fs(jnp.sum(r * r))
             for _ in range(max_inner):
-                Hp = _hvp(X, p, P) + lam * p
-                alpha = rs / max(fs(jnp.sum(p * Hp)), 1e-30)
-                d = d + alpha * p
-                r = r - alpha * Hp
-                rs_new = fs(jnp.sum(r * r))
-                if rs_new < eps:
-                    break
-                p = r + (rs_new / rs) * p
-                rs = rs_new
+                with obs.span(obs.CG):
+                    Hp = _hvp(X, p, P) + lam * p
+                    alpha = rs / max(fs(jnp.sum(p * Hp)), 1e-30)
+                    d = d + alpha * p
+                    r = r - alpha * Hp
+                    rs_new = fs(jnp.sum(r * r))
+                    if rs_new < eps:
+                        break
+                    p = r + (rs_new / rs) * p
+                    rs = rs_new
             B = B + d
     return B, nlls
 

@@ -563,7 +563,8 @@ class Compiled:
         self.staged = ctx.staged
         self._cplan: CompiledPlan = compile_plan(
             planned.eplan, pallas=ctx.pallas, layout=ctx.layout,
-            staged=ctx.staged, strict=ctx.verify == "strict")
+            staged=ctx.staged, strict=ctx.verify == "strict",
+            name=obs.program_name(planned.traced.name))
         self._n_outs = len(planned.eplan.graph.outputs)
         self._vjp_fn = None
         self._bwd_compiled: Optional[CompiledPlan] = None
@@ -613,7 +614,9 @@ class Compiled:
         if self._bwd_compiled is None:
             self._bwd_compiled = compile_plan(
                 bwd.eplan, pallas=self.planned.context.pallas,
-                layout=self.planned.context.layout, staged=self.staged)
+                layout=self.planned.context.layout, staged=self.staged,
+                name=obs.program_name(self.planned.traced.name,
+                                      backward=True))
         ct_names = [n for n in bwd.traced.in_names if n.startswith("__ct")]
         return self._bwd_compiled, bwd.grad_names, ct_names  # type: ignore
 

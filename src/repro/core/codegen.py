@@ -527,6 +527,9 @@ class CompiledPlan:
     #: raise when a costed distributed placement is abandoned at
     #: execution time on a real mesh (FusionContext(verify="strict"))
     strict: bool = False
+    #: name of the staged program's XLA module (``repro.obs.program_name``
+    #: of the region; None: ``plan_fn``)
+    name: Optional[str] = None
     #: per-(spec index, mesh) compiled shard_map callables for the per-op
     #: path (False: not realizable) — keyed by the mesh so a plan
     #: re-targeted at a different real mesh can't reuse a stale executable
@@ -624,6 +627,8 @@ class CompiledPlan:
                       ) -> tuple[Callable, Callable, tuple]:
         with obs.span(obs.CODEGEN) as sp:
             key, plan_fn = self._lower_staged(lanes)
+        if self.name is not None:
+            plan_fn.__name__ = plan_fn.__qualname__ = self.name
         # build-once under concurrency: racing threads compiling
         # structurally-equal plans share one jitted function (and with
         # it one XLA executable per shape signature)
@@ -1233,7 +1238,8 @@ def freed_intermediates(plan: ExecPlan) -> int:
 
 def compile_plan(plan: ExecPlan, pallas: str = "never",
                  layout=None, staged: bool = True,
-                 strict: bool = False) -> CompiledPlan:
+                 strict: bool = False,
+                 name: Optional[str] = None) -> CompiledPlan:
     """Bind an ExecPlan to its executable form.
 
     ``staged=True`` (default) compiles the whole plan into a single
@@ -1244,7 +1250,8 @@ def compile_plan(plan: ExecPlan, pallas: str = "never",
     execution downgrade is recorded on :attr:`CompiledPlan.fallbacks`;
     ``strict=True`` (``FusionContext(verify="strict")``) raises when a
     costed distributed placement on a real mesh is abandoned at
-    execution time.  The per-template dispatch rules are tabulated in
+    execution time.  ``name`` names the staged program's XLA module.
+    The per-template dispatch rules are tabulated in
     ``docs/architecture.md`` (kernel-dispatch decision table)."""
     return CompiledPlan(plan, pallas=pallas, layout=layout, staged=staged,
-                        strict=strict)
+                        strict=strict, name=name)

@@ -29,7 +29,13 @@ Span names, all under :data:`PREFIX`:
 * ``repro.call``: the host binding, canonicalising and dispatching one
   fused region (``Compiled.__call__`` and the planned backward's
   dispatch in the custom VJP);
-* ``repro.sync``: a blocking device-to-host read of the fit loops.
+* ``repro.sync``: a blocking device-to-host read of the fit loops;
+* ``repro.grad``: one value-and-gradient call of the MLogReg fit (the
+  fused forward's and the planned backward's dispatch, and JAX's
+  autodiff glue between them);
+* ``repro.cg``: one inner conjugate-gradient step of the MLogReg fit
+  (the Hessian-vector product's dispatch, its two reads and the eager
+  vector updates).
 
 Counts (:func:`count`) share the table, with no seconds:
 
@@ -40,10 +46,19 @@ Kernel names (:func:`kernel_name`) have the form
 ``<template>_<variant>_<plan digest>`` and are given to every
 ``pallas_call``, so a device trace names each generated kernel; a
 lane-major Row kernel's template token is ``rowt``.
+
+Program names (:func:`program_name`) name each staged whole-plan
+program's XLA module after the fused region it runs (``plan_<region>``,
+``plan_<region>_bwd`` for the region's planned backward), so a device
+trace attributes every operation of a program, XLA fusions included, to
+its region: the trace's ``XLA Modules`` events read ``jit_<name>(...)``.
+Structurally equal plans share one program (``WholePlanCache``), which
+keeps the name of the region that built it first.
 """
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 
@@ -60,11 +75,14 @@ CODEGEN = "repro.codegen"
 STAGE = "repro.stage"
 CALL = "repro.call"
 SYNC = "repro.sync"
+GRAD = "repro.grad"
+CG = "repro.cg"
 ROW_LANES = "repro.kernel.row_lanes"
 
 _lock = threading.Lock()
 _table: dict[str, list] = {}          # name -> [seconds, count]
 _kernels: set[str] = set()
+_programs: set[str] = set()
 _local = threading.local()            # names of the spans open per thread
 
 
@@ -136,3 +154,20 @@ def kernel_names() -> frozenset[str]:
     """Every kernel name registered so far in this process."""
     with _lock:
         return frozenset(_kernels)
+
+
+def program_name(region: str, backward: bool = False) -> str:
+    """``plan_<region>`` (``_bwd`` appended for its planned backward), each
+    character that is no letter, digit or ``_`` made ``_``; registered,
+    as :func:`kernel_name` registers a kernel's."""
+    name = "plan_" + re.sub(r"\W", "_", region) + ("_bwd" if backward
+                                                   else "")
+    with _lock:
+        _programs.add(name)
+    return name
+
+
+def program_names() -> frozenset[str]:
+    """Every program name registered so far in this process."""
+    with _lock:
+        return frozenset(_programs)

@@ -167,3 +167,44 @@ def test_every_kernel_carries_its_name(template, region, shapes):
     assert len(new) == len(cplans) >= 1
     assert {NAME.match(n).groups() for n in new} == {
         (template, cp.variant, cp.cache_key()[:8]) for cp in cplans}
+
+
+@pytest.mark.parametrize("max_inner,eps,cg_steps", [
+    (3, 1e-12, 2 * 3),          # every CG step runs
+    (3, 1e30, 2 * 1),           # the first step's residual test stops it
+])
+def test_mlogreg_fit_opens_grad_and_cg_spans(cls_data, max_inner, eps,
+                                             cg_steps):
+    X, Y, _ypm = cls_data
+    before = obs.snapshot()
+    _w, objs = mlogreg.run(X, Y, max_outer=2, max_inner=max_inner, eps=eps)
+    assert _delta(before, obs.GRAD)[1] == len(objs) == 2
+    assert _delta(before, obs.CG)[1] == cg_steps
+    # two reads per outer iteration, two per CG step
+    assert _delta(before, obs.SYNC)[1] == 2 * len(objs) + 2 * cg_steps
+
+
+# shapes no other test uses, so each program is built (and named) here
+PM, PN, PK = 248, 24, 3
+
+
+@pytest.mark.parametrize("region,backward,name", [
+    ("_probs", False, "plan__softmax_probs_expr"),   # _probs = fused(_softmax_probs_expr)
+    ("_hvp", False, "plan__hvp"),
+    ("_nll_obj_reg", False, "plan__nll_obj_reg"),
+    ("_nll_obj_reg", True, "plan__nll_obj_reg_bwd"),
+])
+def test_staged_program_is_named_by_its_region(region, backward, name):
+    shapes = {"_probs": [(PM, PN), (PN, PK)],
+              "_hvp": [(PM, PN), (PN, PK), (PM, PK)],
+              "_nll_obj_reg": [(PM, PN), (PN, PK), (PM, PK), (1, 1)]}[region]
+    args = [jax.random.normal(jax.random.key(i), s)
+            for i, s in enumerate(shapes)]
+    compiled = getattr(mlogreg, region).trace(*args).plan().compile()
+    cplan = compiled._get_bwd()[0] if backward else compiled._cplan
+    assert cplan.name == name
+    assert name in obs.program_names()
+    fn, _raw = cplan.staged_callable()
+    ins = [jax.ShapeDtypeStruct(tuple(nd.shape), jnp.float32)
+           for nd in cplan.plan.graph.inputs()]
+    assert re.search(rf"module @jit_{name}\b", fn.lower(*ins).as_text())

@@ -177,3 +177,47 @@ def test_l2svm_programs_read_x_in_place(one_chip, program):
     kernels = re.findall(r"%(\w+?)(?:\.\d+)? = \S+ custom-call\(", hlo)
     rows = [k for k in kernels if k.startswith(("row_", "rowt_"))]
     assert rows and all(k.startswith("rowt_") for k in rows), kernels
+
+
+#: one chip's rows of the benchmark's MLogReg cell: a four-chip host's
+#: 4-way share of Mnist8m (2 x CELL_ROWS)
+MLOGREG_ROWS = 2_023_424
+
+
+@pytest.mark.parametrize("program", ["probs", "objective", "objective.grad",
+                                     "hvp"])
+def test_mlogreg_programs_fit_one_chip_at_the_cell_size(one_chip, program):
+    """At 2,023,424 x 784 x 10 the four programs of an MLogReg fit
+    compile for the described v5e with no copy or transpose that produces
+    an f32[2023424,784] value, the HVP lowers to one lane-major
+    ``col_t_agg`` Row kernel, and each program's arguments, outputs and
+    temporaries fit in 13.5e9 bytes of the chip's 16 GB (the backward,
+    X and Y in and dX, dY and dB out, is the largest)."""
+    from repro.algos import mlogreg
+    m, k = MLOGREG_ROWS, 10
+    region, shapes = {
+        "probs": (mlogreg._probs, ((m, N), (N, k))),
+        "objective": (mlogreg._nll_obj_reg, ((m, N), (N, k), (m, k), (1, 1))),
+        "objective.grad": (mlogreg._nll_obj_reg,
+                           ((m, N), (N, k), (m, k), (1, 1))),
+        "hvp": (mlogreg._hvp, ((m, N), (N, k), (m, k))),
+    }[program]
+    compiled = region.trace(*[_S(*s) for s in shapes]).plan(
+        context=FusionContext(pallas="tpu")).compile()
+    cplan = (compiled._get_bwd()[0] if program.endswith(".grad")
+             else compiled._cplan)
+    args = [_sds(tuple(nd.shape), one_chip)
+            for nd in cplan.plan.graph.inputs()]
+    _fn, raw = cplan.staged_callable(args)
+    exe = jax.jit(raw).lower(*args).compile()
+    hlo = exe.as_text()
+    x = rf"f32\[{m},{N}\]\{{[^}}]*\}}"
+    assert not re.search(rf"= {x} (copy|transpose)\(", hlo)
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = \S+ custom-call\(", hlo)
+    if program == "hvp":
+        assert len(kernels) == 1 and kernels[0].startswith(
+            "rowt_col_t_agg_"), kernels
+    mem = exe.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used <= 13.5e9, used
