@@ -22,7 +22,8 @@ Compiled fused operators are first-class JAX citizens:
 * **autodiff** — each dense call runs through a ``jax.custom_vjp`` whose
   backward pass is *itself* planned through explore → select
   (:mod:`repro.core.grad`), so ``jax.grad`` of a ``@fused`` region executes
-  generated fused operators in both directions.
+  generated fused operators in both directions.  Only the gradients of
+  the inputs JAX differentiates are planned and run.
 * **layouts** — ``plan(layout=mesh_or_FusionLayout)`` threads the PR-2
   distributed layout rules onto operator inputs/outputs: reads of
   model-sharded side inputs are costed at ICI bandwidth during selection,
@@ -317,7 +318,8 @@ class Planned:
     traced: Traced
     context: FusionContext
     eplan: ExecPlan
-    _bwd: Optional["Planned"] = field(default=None, repr=False)
+    #: planned backward per frozenset of differentiated input names
+    _bwd: dict = field(default_factory=dict, repr=False)
     #: VerifyReport from the plan() stage boundary (None: verify="off")
     _verify: Optional[VerifyReport] = field(default=None, repr=False)
     #: rewrite-sweep report from Traced.plan() (None: ctx.rewrite=False or
@@ -363,29 +365,39 @@ class Planned:
                         "selected": m == self.context.mode})
         return out
 
-    def backward(self) -> "Planned":
+    def backward(self, wrt=None) -> "Planned":
         """Plan the gradient DAG through the same explore → select pipeline
-        (fused backward operators).  Raises NonDifferentiableError when the
-        forward graph has an op with no VJP rule."""
-        if self._bwd is not None:
-            return self._bwd
+        (fused backward operators).  ``wrt`` names the inputs whose
+        gradients the DAG computes (None: every input); gradients no
+        caller reads are not planned, so their passes and outputs never
+        run.  ``grad_names`` lists the planned gradients in input order.
+        Raises NonDifferentiableError when the forward graph has an op
+        with no VJP rule."""
+        fwd_inputs = [n.name for n in self.eplan.graph.inputs()]
+        if wrt is not None:
+            want = set(wrt)
+            wrt = [n for n in fwd_inputs if n in want]
+        key = frozenset(fwd_inputs if wrt is None else wrt)
+        if key in self._bwd:
+            return self._bwd[key]
         with obs.span(obs.PLAN):
             ct_names, grads = vjp_graph(self.eplan.graph)
-            fwd_inputs = [n.name for n in self.eplan.graph.inputs()]
-            bgraph = ir.Graph.build([grads[n] for n in fwd_inputs])
+            grad_names = fwd_inputs if wrt is None else wrt
+            bgraph = ir.Graph.build([grads[n] for n in grad_names])
             in_meta = dict(self.traced.in_meta)
             for name, o in zip(ct_names, self.eplan.graph.outputs):
                 in_meta[name] = {"shape": o.shape, "format": "dense",
                                  "sparsity": 1.0}
             btr = Traced(self.traced.name + ":vjp", bgraph,
                          list(self.traced.in_names) + ct_names, in_meta)
-            self._bwd = _verified_planned(
+            bwd = _verified_planned(
                 btr, self.context,
                 plan_graph(bgraph, self.context.mode,
                            layout_cost_params(self.context.layout, bgraph,
                                               self.context.params)))
-            self._bwd.grad_names = fwd_inputs   # type: ignore[attr-defined]
-        return self._bwd
+            bwd.grad_names = grad_names   # type: ignore[attr-defined]
+        self._bwd[key] = bwd
+        return bwd
 
     def explain(self, include_backward: bool = False) -> dict:
         """Structured plan report (same shape as the layout planner's
@@ -545,7 +557,7 @@ class Planned:
                 self.eplan, strict=ctx.verify == "strict",
                 pallas=ctx.pallas, layout=ctx.layout))
             report.raise_if_errors()
-        return Compiled(replace(self, context=ctx))
+        return Compiled(replace(self, context=ctx, _bwd=dict(self._bwd)))
 
 
 # --------------------------------------------------------------------------
@@ -567,7 +579,8 @@ class Compiled:
             name=obs.program_name(planned.traced.name))
         self._n_outs = len(planned.eplan.graph.outputs)
         self._vjp_fn = None
-        self._bwd_compiled: Optional[CompiledPlan] = None
+        #: compiled backward per frozenset of differentiated input names
+        self._bwd_compiled: dict[frozenset, CompiledPlan] = {}
 
     # -- serving hooks ------------------------------------------------------
     @property
@@ -609,20 +622,34 @@ class Compiled:
                 outs = lay.apply("__out0", outs)
         return outs
 
-    def _get_bwd(self) -> tuple[CompiledPlan, list[str], list[str]]:
-        bwd = self.planned.backward()
-        if self._bwd_compiled is None:
-            self._bwd_compiled = compile_plan(
+    def _get_bwd(self, wrt=None) -> tuple[CompiledPlan, list[str],
+                                          list[str]]:
+        """(compiled backward, the gradients it returns, its cotangent
+        input names) for the inputs named in ``wrt`` (None: every input).
+        Each differentiated set compiles its own program, all named
+        ``plan_<region>_bwd``."""
+        bwd = self.planned.backward(wrt)
+        key = frozenset(bwd.grad_names)  # type: ignore[attr-defined]
+        cp = self._bwd_compiled.get(key)
+        if cp is None:
+            cp = self._bwd_compiled[key] = compile_plan(
                 bwd.eplan, pallas=self.planned.context.pallas,
                 layout=self.planned.context.layout, staged=self.staged,
                 name=obs.program_name(self.planned.traced.name,
                                       backward=True))
         ct_names = [n for n in bwd.traced.in_names if n.startswith("__ct")]
-        return self._bwd_compiled, bwd.grad_names, ct_names  # type: ignore
+        return cp, bwd.grad_names, ct_names  # type: ignore[attr-defined]
 
     def _build_vjp(self):
+        """The ``custom_vjp`` every dense call runs through.  ``fwd`` reads
+        which inputs JAX perturbs (static at trace time) and hands the
+        set to ``bwd`` in the residuals' tree structure; ``bwd`` runs the
+        backward planned for that set only and returns None (a zero
+        cotangent) for every other input."""
         import jax
+        from jax.custom_derivatives import SymbolicZero
         names = list(self.planned.traced.in_names)
+        graph_inputs = {n.name for n in self.planned.eplan.graph.inputs()}
 
         def run(*arrs):
             return self._run_plain(dict(zip(names, arrs)))
@@ -631,25 +658,35 @@ class Compiled:
         def call(*arrs):
             return run(*arrs)
 
-        def fwd(*arrs):
-            return run(*arrs), arrs          # residuals: primal inputs only
+        def fwd(*primals):
+            arrs = tuple(p.value for p in primals)
+            # residuals: the primal inputs, and the perturbed names as
+            # dict keys, which the residual tree carries statically
+            wrt = {n: None for n, p in zip(names, primals) if p.perturbed}
+            return run(*arrs), (arrs, wrt)
 
         def bwd(res, ct):
+            arrs, wrt = res
+            planned = [n for n in names if n in wrt and n in graph_inputs]
+            if not planned:
+                return (None,) * len(names)
             with obs.span(obs.CALL):
-                bwd_plan, grad_names, ct_names = self._get_bwd()
+                if len(planned) < len(graph_inputs):
+                    obs.count(obs.BWD_PRUNED)
+                bwd_plan, grad_names, ct_names = self._get_bwd(planned)
                 cts = ct if isinstance(ct, (tuple, list)) else (ct,)
-                binds = dict(zip(names, res))
-                binds.update({n: jnp.asarray(c, jnp.float32)
+                binds = dict(zip(names, arrs))
+                binds.update({n: jnp.zeros(c.shape, jnp.float32)
+                              if isinstance(c, SymbolicZero)
+                              else jnp.asarray(c, jnp.float32)
                               for n, c in zip(ct_names, cts)})
                 grads = bwd_plan(binds)
                 if not isinstance(grads, tuple):
                     grads = (grads,)
                 by_name = dict(zip(grad_names, grads))
-                return tuple(by_name.get(n) if n in by_name
-                             else jnp.zeros_like(res[i])
-                             for i, n in enumerate(names))
+                return tuple(by_name.get(n) for n in names)
 
-        call.defvjp(fwd, bwd)
+        call.defvjp(fwd, bwd, symbolic_zeros=True)
         return call
 
     # -- calling ------------------------------------------------------------
@@ -667,8 +704,7 @@ class Compiled:
             chosen = {e["specs"][0]: e for e in self._cplan.row_orientation}
             report["execution"]["row_orientation"] = [
                 chosen.get(e["specs"][0], e) for e in rows]
-        bwd = self._bwd_compiled
-        if bwd is not None:
+        for bwd in self._bwd_compiled.values():
             seen = {(f["site"], f["reason"]) for f in static}
             for f in bwd.fallbacks:
                 if (f["site"], f["reason"]) not in seen:

@@ -15,8 +15,9 @@ lanes: the kernel reads the main's transpose, an (n, m) array, in
 (n, tm) blocks, every m-row side input and the ``no_agg``/``row_agg``
 output as its (c, m) transpose in (c, tm) blocks, and runs the program on
 the transposed values (``panel @ W`` becomes ``Wᵀ @ panelᵀ``, or a
-sublane reduce for one column; row and column reductions swap axes).
-For an array the device stores column-major, as a TPU stores an
+sublane reduce for one column; row and column reductions swap axes;
+a ``col_t_agg`` close over one column is an f32 lane reduce, wider
+closes an MXU product).  For an array the device stores column-major, as a TPU stores an
 (m, 784) or (m, 1) f32 array, each transpose is a bitcast, so the kernel
 reads the array's own bytes: no relayout copy, no lane padding of 784
 to 896 or of 1 to 128.  Resident inputs stay whole, in the form the
@@ -346,7 +347,12 @@ def _row_pallas_lanes(cplan: CPlan, env: dict[int, jnp.ndarray], *,
         if variant == ROW_AGG:
             out[...] = _panel_reduce(val, agg, axis=0).astype(dtype)
             return
-        if variant == COL_T_AGG:
+        if variant == COL_T_AGG and val.shape[0] == 1:
+            # closerᵀ @ one column: an f32 multiply and a lane reduce,
+            # as a single-column product of the program is formed
+            part = jnp.sum(vals[1] * val, axis=1,
+                           keepdims=True).astype(dtype)
+        elif variant == COL_T_AGG:
             part = jax.lax.dot_general(
                 vals[1], val, (((1,), (1,)), ((), ()))).astype(dtype)
         elif variant == COL_AGG:

@@ -40,7 +40,10 @@ Span names, all under :data:`PREFIX`:
 Counts (:func:`count`) share the table, with no seconds:
 
 * ``repro.kernel.row_lanes``: a Row kernel lowered in its lane-major
-  orientation (:mod:`repro.kernels.rowwise`), once per lowering.
+  orientation (:mod:`repro.kernels.rowwise`), once per lowering;
+* ``repro.bwd.pruned``: a planned backward run for fewer inputs than its
+  region has, because JAX differentiates only those
+  (``Compiled._build_vjp``), once per backward call.
 
 Kernel names (:func:`kernel_name`) have the form
 ``<template>_<variant>_<plan digest>`` and are given to every
@@ -78,6 +81,7 @@ SYNC = "repro.sync"
 GRAD = "repro.grad"
 CG = "repro.cg"
 ROW_LANES = "repro.kernel.row_lanes"
+BWD_PRUNED = "repro.bwd.pruned"
 
 _lock = threading.Lock()
 _table: dict[str, list] = {}          # name -> [seconds, count]

@@ -216,6 +216,67 @@ def test_row_kernel_lane_major_vector_main(which):
     _check_lanes(cp, env)
 
 
+def _bwd_row(region, shapes, wrt):
+    """The one Row operator of ``region``'s backward planned for the
+    inputs named in ``wrt``."""
+    from repro.core.codegen import PLAN_CACHE
+    from repro.core.templates import TType
+    S = lambda *s: np.zeros(s, np.float32)
+    bwd = region.trace(*[S(*s) for s in shapes]).plan(
+        mode="gen").backward(wrt)
+    g = bwd.eplan.graph
+    (cp,) = [cp for cp in (PLAN_CACHE.get_or_build(g, s)[1]
+                           for s in bwd.eplan.specs if getattr(s, "fused", 0))
+             if cp.ttype == TType.ROW]
+    return cp
+
+
+def _dot_generals(jaxpr) -> list:
+    """Dimension numbers of every dot_general in ``jaxpr`` and in the
+    jaxprs its equations hold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["dimension_numbers"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _dot_generals(sub)
+    return found
+
+
+@pytest.mark.parametrize("k", [1, 10])
+def test_row_kernel_lane_major_col_t_agg_close(k):
+    """The lane-major ``col_t_agg`` close of the fits' weight-only
+    backwards, Xᵀ·g over two grid steps at 784 columns: equal to the f32
+    oracle, and formed for one column (L2SVM's dw) as an f32 multiply and
+    lane reduce with no MXU product in the kernel, for ten (MLogReg's dB)
+    by the MXU product, contracting the rows."""
+    import jax
+    from repro.algos import l2svm, mlogreg
+    m, n = 2048, 784
+    region, wrt = ((l2svm._objective_full, ["w"]) if k == 1
+                   else (mlogreg._nll_obj_reg, ["B"]))
+    cp = _bwd_row(region, [(m, n), (n, k), (m, k), (1, 1)], wrt)
+    assert cp.variant == "col_t_agg" and tuple(cp.out_shape) == (n, k)
+    env = {b.nid: jnp.asarray(0.1 * rng.normal(size=b.shape), jnp.float32)
+           for b in cp.binds}
+    exp = np.asarray(ref.execute_dense(cp, env))
+    got = np.asarray(row_pallas(cp, env, interpret=True, lanes=True))
+    np.testing.assert_allclose(got, exp, rtol=1e-5,
+                               atol=1e-5 * float(np.abs(exp).max()))
+    jaxpr = jax.make_jaxpr(
+        lambda e: row_pallas(cp, e, interpret=True, lanes=True))(env)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    dots = _dot_generals(call.params["jaxpr"])
+    if k == 1:
+        assert dots == []
+    else:
+        assert (((1,), (1,)), ((), ())) in dots, dots
+
+
 def _random_bcsr(mb, nb, bs, density, rng):
     mask = rng.random((mb, nb)) < density
     mask.flat[0] = True

@@ -193,6 +193,7 @@ PM, PN, PK = 248, 24, 3
     ("_hvp", False, "plan__hvp"),
     ("_nll_obj_reg", False, "plan__nll_obj_reg"),
     ("_nll_obj_reg", True, "plan__nll_obj_reg_bwd"),
+    ("_nll_obj_reg", ("B",), "plan__nll_obj_reg_bwd"),
 ])
 def test_staged_program_is_named_by_its_region(region, backward, name):
     shapes = {"_probs": [(PM, PN), (PN, PK)],
@@ -201,10 +202,29 @@ def test_staged_program_is_named_by_its_region(region, backward, name):
     args = [jax.random.normal(jax.random.key(i), s)
             for i, s in enumerate(shapes)]
     compiled = getattr(mlogreg, region).trace(*args).plan().compile()
-    cplan = compiled._get_bwd()[0] if backward else compiled._cplan
+    wrt = None if backward is True else backward    # a tuple: only those
+    cplan = compiled._get_bwd(wrt)[0] if backward else compiled._cplan
     assert cplan.name == name
     assert name in obs.program_names()
     fn, _raw = cplan.staged_callable()
     ins = [jax.ShapeDtypeStruct(tuple(nd.shape), jnp.float32)
            for nd in cplan.plan.graph.inputs()]
     assert re.search(rf"module @jit_{name}\b", fn.lower(*ins).as_text())
+
+
+def test_pruned_backward_is_counted():
+    """``repro.bwd.pruned`` counts each backward call planned for fewer
+    inputs than its region has, and no call that differentiates every
+    input; the pruned one still computes the weights' gradient."""
+    X = jax.random.normal(jax.random.key(0), (136, 12))
+    w = jax.random.normal(jax.random.key(1), (12, 1))
+    y = jnp.sign(jax.random.normal(jax.random.key(2), (136, 1)))
+    lam = jnp.full((1, 1), 1e-3, jnp.float32)
+    loss = lambda *a: l2svm._objective_full(*a)[0, 0]
+    before = obs.snapshot()
+    g_w = [jax.grad(loss, argnums=1)(X, w, y, lam) for _ in range(2)]
+    assert _delta(before, obs.BWD_PRUNED)[1] == 2
+    g_all = jax.grad(loss, argnums=(0, 1, 2, 3))(X, w, y, lam)
+    assert _delta(before, obs.BWD_PRUNED)[1] == 2
+    np.testing.assert_allclose(np.asarray(g_w[1]), np.asarray(g_all[1]),
+                               rtol=1e-5, atol=1e-5)

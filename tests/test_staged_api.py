@@ -153,6 +153,86 @@ def test_backward_is_planned_fused():
     assert templates & {"CELL", "ROW", "MAGG", "MAGG(multi)"}
 
 
+def _weight_case(which):
+    """(region, operands, positions of the weights, hand-derived weight
+    gradient or None) of an algorithm's differentiated region at a small
+    shape."""
+    from repro.algos import autoencoder, l2svm, mlogreg
+    own = np.random.default_rng(17)     # leaves the module's stream as is
+    arr = lambda *s: jnp.asarray(own.normal(size=s), jnp.float32)
+    lam = jnp.full((1, 1), 1e-3, jnp.float32)
+    if which == "l2svm":
+        X, w = arr(300, 20), arr(20, 1)
+        y = jnp.sign(arr(300, 1))
+        hand = lambda: l2svm._grad(X, l2svm._hinge(X, w, y), y, w, lam)
+        return l2svm._objective_full, (X, w, y, lam), (1,), hand
+    if which == "mlogreg":
+        m, n, k = 400, 12, 4
+        X, B = arr(m, n), arr(n, k) * 0.1
+        Y = jnp.asarray(np.eye(k, dtype=np.float32)[own.integers(0, k, m)])
+        hand = lambda: (mlogreg._grad(X, mlogreg._probs(X, B), Y)
+                        + lam * B)
+        return mlogreg._nll_obj_reg, (X, B, Y, lam), (1,), hand
+    dims = [(16, 8), (1, 8), (8, 2), (1, 2), (2, 8), (1, 8), (8, 16),
+            (1, 16)]
+    params = tuple(arr(*d) * 0.3 for d in dims)
+    return (autoencoder._recon_loss, (arr(64, 16),) + params,
+            tuple(range(1, 9)), None)
+
+
+@pytest.mark.parametrize("which", ["l2svm", "mlogreg", "autoencoder"])
+def test_weight_only_grad_matches_all_inputs_backward(which):
+    """value_and_grad over the weights alone runs the backward planned for
+    them only; its gradient equals the all-inputs backward's weight
+    gradient, and the hand-derived fused gradient, to 1e-5."""
+    region, ops, wpos, hand = _weight_case(which)
+    loss = lambda *a: region(*a)[0, 0]
+    with FusionContext(mode="gen", pallas="interpret"):
+        val, g_w = jax.value_and_grad(loss, argnums=wpos)(*ops)
+        g_all = jax.grad(loss, argnums=tuple(range(len(ops))))(*ops)
+        g_hand = hand() if hand is not None else None
+        np.testing.assert_allclose(float(val), float(loss(*ops)), rtol=1e-6)
+    for got, p in zip(g_w, wpos):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(g_all[p]),
+                                   rtol=1e-5, atol=1e-5)
+    if g_hand is not None:
+        np.testing.assert_allclose(np.asarray(g_w[0]), np.asarray(g_hand),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_grad_over_data_and_weights_keeps_dx():
+    """Differentiating X as well as w still returns dX = −(out⊙y)wᵀ."""
+    region, (X, w, y, lam), _wpos, _hand = _weight_case("l2svm")
+    with FusionContext(mode="gen", pallas="interpret"):
+        dX, dw = jax.grad(
+            lambda X_, w_: region(X_, w_, y, lam)[0, 0],
+            argnums=(0, 1))(X, w)
+    out = jnp.maximum(1.0 - y * (X @ w), 0.0)
+    np.testing.assert_allclose(np.asarray(dX), np.asarray(-(out * y) @ w.T),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(dw),
+                               np.asarray(-X.T @ (out * y) + lam * w),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("which", ["l2svm", "mlogreg"])
+def test_weight_only_backward_is_one_row_pass(which):
+    """Planned for the weights alone, the backward is one Row operator
+    rooted at the Xᵀ· product, with X among its inputs: X is read once,
+    and no dX, dY or second X @ w pass is planned."""
+    region, ops, wpos, _hand = _weight_case(which)
+    planned = region.trace(*ops).plan(mode="gen")
+    wname = region.names[wpos[0]]
+    bwd, full = planned.backward([wname]), planned.backward()
+    assert bwd.grad_names == [wname]
+    assert sorted(full.grad_names) == sorted(region.names)
+    rows = [o for o in bwd.fused_signatures() if o["template"] == "ROW"]
+    assert len(rows) == 1, bwd.fused_signatures()
+    assert rows[0]["root"] == "matmul" and "X" in rows[0]["inputs"]
+    assert bwd.cost < full.cost
+    assert planned.backward([wname]) is bwd        # one plan per set
+
+
 def test_value_and_grad_multi_output():
     f = fused(lambda X, Y: ((X * Y).sum(), (X ** 2).sum()))
     X, Y = arr(30, 10), arr(30, 10)

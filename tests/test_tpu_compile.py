@@ -221,3 +221,41 @@ def test_mlogreg_programs_fit_one_chip_at_the_cell_size(one_chip, program):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used <= 13.5e9, used
+
+
+@pytest.mark.parametrize("algo", ["l2svm", "mlogreg"])
+def test_weight_only_backward_reads_x_once(one_chip, algo):
+    """The backward the fits run, planned for the weights alone, at the
+    cells' sizes: one lane-major ``rowt_col_t_agg_*`` Row kernel reads X
+    through a bitcast, and nothing of X's shape is written, copied or
+    transposed (no dX).  MLogReg's program fits in 7.5e9 bytes, about X
+    alone, where the all-inputs backward needs 12.95e9."""
+    from repro.algos import l2svm, mlogreg
+    region, m, k, w = {
+        "l2svm": (l2svm._objective_full, CELL_ROWS, 1, "w"),
+        "mlogreg": (mlogreg._nll_obj_reg, MLOGREG_ROWS, 10, "B"),
+    }[algo]
+    shapes = ((m, N), (N, k), (m, k), (1, 1))
+    compiled = region.trace(*[_S(*s) for s in shapes]).plan(
+        context=FusionContext(pallas="tpu")).compile()
+    cplan, grad_names, _cts = compiled._get_bwd([w])
+    assert grad_names == [w]
+    assert cplan.name == obs.program_name(region.fn.__name__, backward=True)
+    args = [_sds(tuple(nd.shape), one_chip)
+            for nd in cplan.plan.graph.inputs()]
+    _fn, raw = cplan.staged_callable(args)
+    exe = jax.jit(raw).lower(*args).compile()
+    hlo = exe.as_text()
+    x = rf"f32\[{m},{N}\]\{{[^}}]*\}}"
+    assert not re.search(rf"= {x} (copy|transpose)\(", hlo)
+    assert not re.search(rf"->.*f32\[{m},{N}\]", hlo.splitlines()[0])
+    assert re.search(rf"= f32\[{N},{m}\]\{{[^}}]*\}} bitcast\(", hlo)
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = \S+ custom-call\(", hlo)
+    rows = [n for n in kernels if n.startswith(("row_", "rowt_"))]
+    assert len(rows) == 1 and rows[0].startswith("rowt_col_t_agg_"), kernels
+    mem = exe.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert mem.output_size_in_bytes < 1e6, mem       # the gradient alone
+    if algo == "mlogreg":
+        assert used <= 7.5e9, used
