@@ -52,8 +52,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels.blocksparse import BCSR, DictCompressed
 from . import ir
-from .codegen import (CompiledPlan, compile_plan, freed_intermediates,
-                      plan_fallbacks, row_orientations)
+from .codegen import CompiledPlan, compile_plan, plan_steps
 from .context import FusionContext, current_context
 from .cost import CostParams
 from .grad import vjp_graph
@@ -427,6 +426,8 @@ class Planned:
         ``include_backward=True`` appends the planned gradient DAG's
         report (see :meth:`backward`)."""
         ex, en = self.eplan.explore_stats, self.eplan.enum_stats
+        steps = plan_steps(self.eplan, self.context.layout,
+                           self.context.pallas)
         report = {
             "expression": self.traced.name,
             "mode": self.context.mode,
@@ -457,14 +458,11 @@ class Planned:
                 "dispatches_per_call": 1 if self.context.staged
                 else len(self.eplan.specs),
                 "donated_inputs": [],       # inputs are never donated
-                "freed_intermediates": freed_intermediates(self.eplan),
+                "freed_intermediates": sum(map(len, steps.free)),
                 # every statically-known execution downgrade, with its
                 # reason; Compiled.explain() merges the runtime-recorded
                 # entries (value-format downgrades seen at call time)
-                "fallbacks": plan_fallbacks(
-                    self.eplan, layout=self.context.layout,
-                    pallas=self.context.pallas,
-                    staged=self.context.staged),
+                "fallbacks": steps.fallback_report(self.context.staged),
             },
             "layout": None,
         }
@@ -472,9 +470,7 @@ class Planned:
             # each Row kernel's orientation (row- or lane-major), decided
             # per call by its main's device layout; Compiled.explain()
             # puts in what the last call chose
-            report["execution"]["row_orientation"] = row_orientations(
-                self.eplan, pallas=self.context.pallas,
-                layout=self.context.layout)
+            report["execution"]["row_orientation"] = steps.orientations()
         if self._verify is None and self.context.verify != "off":
             self._verify = verify_plan(self.eplan,
                                        level=self.context.verify,
@@ -699,11 +695,10 @@ class Compiled:
         for f in self._cplan.fallbacks:
             if (f["site"], f["reason"]) not in seen:
                 static.append(dict(f))
-        rows = report["execution"].get("row_orientation")
-        if rows is not None:        # what the last call chose, per kernel
-            chosen = {e["specs"][0]: e for e in self._cplan.row_orientation}
-            report["execution"]["row_orientation"] = [
-                chosen.get(e["specs"][0], e) for e in rows]
+        if "row_orientation" in report["execution"]:
+            # what the last call chose, per kernel
+            report["execution"]["row_orientation"] = \
+                self._cplan.row_orientation
         for bwd in self._bwd_compiled.values():
             seen = {(f["site"], f["reason"]) for f in static}
             for f in bwd.fallbacks:

@@ -28,6 +28,14 @@ by the EXE005 verifier invariant and by ``fusionlint --strict``, and
 raise under ``FusionContext(verify="strict")`` when a costed distributed
 placement is abandoned at execution time.
 
+One walk over the plan, :func:`plan_steps`, decides how an ExecPlan
+becomes staged steps: which specs run as ``shard_map`` segments, which
+as local fused kernels, which take their XLA body instead of a Pallas
+kernel, which local Row kernels may run lane-major, and the structural
+whole-plan key.  The lowering (:class:`CompiledPlan`),
+:func:`staged_plan_key`, :func:`plan_fallbacks` and
+:func:`row_orientations` all read its :class:`StepTable`.
+
 Execution paths per operator are chosen by the dispatcher in
 ``kernels/ops.py`` (dense XLA, dense Pallas, BCSR sparsity-exploiting,
 CLA-compressed); the full kernel-dispatch decision table lives in
@@ -460,8 +468,7 @@ def _spec_roots(spec) -> tuple[int, ...]:
 
 def _segment_items(graph: Graph, plan: ExecPlan, seg,
                    cache: PlanCache) -> list:
-    """SegmentItems for one plan Segment — shared by the staged lowering
-    and the static fallback report so the two can never drift."""
+    """SegmentItems for one plan Segment (:func:`plan_steps`)."""
     from repro.kernels.distributed import SegmentItem
     specs = plan.specs
     output_ids = set(graph.output_ids)
@@ -479,6 +486,206 @@ def _segment_items(graph: Graph, plan: ExecPlan, seg,
                      for r in roots)
         items.append(SegmentItem(cplan, spec.placement, roots, export))
     return items
+
+
+def _fused(spec) -> bool:
+    return isinstance(spec, MultiAggSpec) or (
+        isinstance(spec, FusedOpSpec) and spec.fused)
+
+
+@dataclass(frozen=True)
+class StepTable:
+    """How an ExecPlan runs as one staged function under one layout and
+    Pallas mode.  :func:`plan_steps` makes every one of these decisions;
+    the lowering, the whole-plan key, ``explain()``, the verifier and
+    ``fusionlint`` read them from here.
+
+    ``steps`` run in order, each one of ``("seg", SegmentPlan, the roots
+    of each exported member)``, a ``shard_map`` region; ``("fused",
+    cplan, bind nids, roots, main position)``, a local fused operator;
+    ``("basic", node)``.  ``key`` is the structural whole-plan key of
+    the row-major lowering.  ``fallbacks`` lists each plan-time
+    downgrade as (site, spec indices, reason) in step order: a
+    ``segment`` or ``operator`` placement the mesh cannot realize, and a
+    dense ``kernel`` that takes its XLA body at the shapes its step
+    sees.  ``rows`` has (spec index, main position, reason) for each
+    local dense Row kernel: the main's input position where each call's
+    main decides the orientation (the fused step carries the same
+    position), else the reason it stays row-major.  ``free[i]`` are the
+    values dead after step ``i``."""
+    steps: tuple
+    key: tuple
+    fallbacks: tuple
+    rows: tuple
+    free: tuple
+
+    def fallback_report(self, staged: bool = True) -> list:
+        """The plan-time part of ``explain()["execution"]["fallbacks"]``:
+        segments, then operators outside a segment already reported,
+        then kernels."""
+        out = [] if staged else [{
+            "site": "plan",
+            "reason": "staged=False: per-operator debug dispatch requested"}]
+        fell = {j for site, specs, _r in self.fallbacks if site == "segment"
+                for j in specs}
+        for want in ("segment", "operator", "kernel"):
+            out.extend({"site": site, "specs": list(specs), "reason": reason}
+                       for site, specs, reason in self.fallbacks
+                       if site == want and not (site == "operator"
+                                                and specs[0] in fell))
+        return out
+
+    def orientations(self) -> list:
+        """The static ``explain()["execution"]["row_orientation"]``: each
+        local dense Row kernel ``row_major`` with its reason, or
+        ``by_layout`` where each call's main decides."""
+        return [{"specs": [idx], "orientation": "row_major",
+                 "reason": reason} if pos is None else
+                {"specs": [idx], "orientation": "by_layout"}
+                for idx, pos, reason in self.rows]
+
+
+def plan_steps(plan: ExecPlan, layout=None, pallas: str = "never",
+               cache: Optional[PlanCache] = None) -> StepTable:
+    """The :class:`StepTable` of ``plan`` under ``layout`` and ``pallas``,
+    from one walk over ``plan.specs``, tracing nothing.  Under a mesh a
+    plan segment, else a ``distributed`` operator alone, becomes a
+    ``shard_map`` step where :func:`~repro.kernels.distributed.
+    plan_segment` realizes it; the rest run as local steps.  Every value
+    a step consumes resolves to a canonical token, so a ``KeyError``
+    here means the plan wires a value no step produces (the verifier's
+    EXE004)."""
+    from repro.kernels.distributed import (SegmentFallback, SegmentItem,
+                                           plan_segment)
+    cache = cache if cache is not None else PLAN_CACHE
+    graph, specs = plan.graph, plan.specs
+    mesh = _mesh_of(layout)
+    in_pos = {n.nid: p for p, n in enumerate(graph.inputs())}
+    output_ids = tuple(o.nid for o in graph.outputs)
+
+    # canonical env tokens: whole-plan keys must capture the wiring,
+    # not the node ids (structurally-equal plans from other traces
+    # must hit)
+    canon: dict[int, tuple] = {nid: ("in", p) for nid, p in in_pos.items()}
+    for n in graph.nodes:
+        if n.op == "lit":
+            canon[n.nid] = ("lit", float(n.attrs["value"]))
+
+    steps: list[tuple] = []
+    key_parts: list[tuple] = []      # structural key, one per step
+    spec_step: dict[int, int] = {}   # spec idx -> step idx
+    fallbacks: list[tuple] = []
+    rows: list[tuple] = []
+
+    def _token(roots: tuple[int, ...], step_idx: int,
+               item_idx: int = 0) -> None:
+        # the item index distinguishes the members of one segment
+        # step — without it two outputs of the same step would be
+        # indistinguishable in the whole-plan key and a structurally
+        # different consumer wiring could hit the wrong function
+        for k, r in enumerate(roots):
+            canon[r] = ("s", step_idx, item_idx, k)
+
+    def _kernel(idx: int, cplan: CPlan, shapes: dict) -> Optional[str]:
+        # why the operator takes its XLA body at these shapes (reported
+        # for a dense main: the planner saw a sparse one run BCSR rows)
+        reason = kops.kernel_fallback(cplan, shapes)
+        if reason is not None and cplan.main.sparsity >= 1.0:
+            fallbacks.append(("kernel", (idx,), reason))
+        return reason
+
+    def _seg_step(items: list, sp, indices: tuple) -> None:
+        step_idx = len(steps)
+        steps.append(("seg", sp, tuple(it.roots for it in items
+                                       if it.export)))
+        key_parts.append((
+            "seg", mesh,
+            tuple((it.cplan.cache_key(), it.placement.epilogue,
+                   tuple(b.nid in it.placement.sharded
+                         for b in it.cplan.binds), it.export)
+                  for it in items),
+            tuple(canon[nid] for nid in sp.ext)))
+        for k, (j, it) in enumerate(zip(indices, items)):
+            spec_step[j] = step_idx
+            _token(it.roots, step_idx, k)
+            if pallas != "never":
+                _kernel(j, it.cplan, sp.local_shapes(k))
+
+    def _fused_step(idx: int, cplan: CPlan, roots: tuple) -> None:
+        step_idx = len(steps)
+        bind_nids = tuple(b.nid for b in cplan.binds)
+        key_parts.append(("fused", cplan.cache_key(),
+                          tuple(canon[nid] for nid in bind_nids)))
+        main_pos = None
+        if pallas != "never":
+            shapes = {b.nid: tuple(b.shape) for b in cplan.binds}
+            if _kernel(idx, cplan, shapes) is None \
+                    and cplan.ttype == TType.ROW and not cplan.extra:
+                reason = (lane_forms(cplan)[1]
+                          or kops.kernel_fallback(cplan, shapes, lanes=True))
+                if reason is None and cplan.main.nid not in in_pos:
+                    reason = (f"main %{cplan.main.nid} is computed inside "
+                              f"the plan: its device layout is not observed")
+                if reason is None:
+                    main_pos = in_pos[cplan.main.nid]
+                rows.append((idx, main_pos, reason))
+        steps.append(("fused", cplan, bind_nids, roots, main_pos))
+        spec_step[idx] = step_idx
+        _token(roots, step_idx)
+
+    seg_start = ({seg.indices[0]: seg for seg in plan.segments}
+                 if mesh is not None else {})
+    idx = 0
+    while idx < len(specs):
+        seg = seg_start.get(idx)
+        if seg is not None:
+            items = _segment_items(graph, plan, seg, cache)
+            sp = plan_segment(items, mesh)
+            if not isinstance(sp, SegmentFallback):
+                _seg_step(items, sp, seg.indices)
+                idx = seg.indices[-1] + 1
+                continue
+            # the mesh cannot realize the costed placement: the members
+            # run as local fused steps
+            fallbacks.append(("segment", tuple(seg.indices), sp.reason))
+        spec = specs[idx]
+        if _fused(spec):
+            _op, cplan = cache.get_or_build(graph, spec)
+            roots = _spec_roots(spec)
+            pl = getattr(spec, "placement", None)
+            sp = None
+            if mesh is not None and pl is not None \
+                    and pl.arm == "distributed":
+                items = [SegmentItem(cplan, pl, roots, True)]
+                sp = plan_segment(items, mesh)
+                if isinstance(sp, SegmentFallback):
+                    fallbacks.append(("operator", (idx,), sp.reason))
+                    sp = None
+            if sp is not None:
+                _seg_step(items, sp, (idx,))
+            else:
+                _fused_step(idx, cplan, roots)
+        else:
+            node = graph.by_id[spec.root]
+            spec_step[idx] = len(steps)
+            steps.append(("basic", node))
+            key_parts.append((
+                "basic", node.op,
+                tuple(sorted(node.attrs.items())), node.shape,
+                tuple(canon[i.nid] if i.op != "lit"
+                      else ("lit", float(i.attrs["value"]))
+                      for i in node.inputs)))
+            canon[spec.root] = ("s", spec_step[idx], 0, 0)
+        idx += 1
+
+    # dead intermediates, re-indexed from spec positions to steps
+    free: list[list[int]] = [[] for _ in steps]
+    for sidx, dead in _last_uses(plan).items():
+        free[spec_step[sidx]].extend(d for d in dead if d not in output_ids)
+    key = (tuple(key_parts), tuple(canon[o] for o in output_ids), pallas,
+           tuple(getattr(plan, "rewrite", ()) or ()))
+    return StepTable(tuple(steps), key, tuple(fallbacks), tuple(rows),
+                     tuple(tuple(f) for f in free))
 
 
 @dataclass
@@ -536,22 +743,12 @@ class CompiledPlan:
     _dist_fns: dict = field(default_factory=dict, repr=False)
     #: literal (1, 1) arrays, built once per plan (per-op path)
     _lit_cache: Optional[dict] = field(default=None, repr=False)
-    #: jitted whole-plan function + its un-jitted trace (introspection)
-    _staged_fn: Optional[Callable] = field(default=None, repr=False)
-    _staged_raw: Optional[Callable] = field(default=None, repr=False)
-    #: structural whole-plan cache key of the staged lowering
-    _staged_key: Optional[tuple] = field(default=None, repr=False)
-    #: lane-major staged lowerings: frozenset of the input positions
-    #: whose Row kernels run lane-major -> (jitted, raw, key)
-    _staged_lanes: dict = field(default_factory=dict, repr=False)
-    #: input position -> spec indices of the local Row kernels it is the
-    #: main of and that have a lane-major form; and every local Row
-    #: kernel's orientation entry (set by the staged lowering, updated
-    #: per call: ``explain()["execution"]["row_orientation"]``)
-    _lane_mains: dict = field(default_factory=dict, repr=False)
-    _row_orient: dict = field(default_factory=dict, repr=False)
-    #: mesh-validated SegmentPlans of the staged lowering (real mesh)
-    _seg_plans: list = field(default_factory=list, repr=False)
+    #: staged lowerings by the input positions whose Row kernels run
+    #: lane-major (frozenset(): the row-major one) -> (jitted, raw, key)
+    _staged: dict = field(default_factory=dict, repr=False)
+    #: spec index -> the orientation the last call chose for that Row
+    #: kernel (``explain()["execution"]["row_orientation"]``)
+    _chosen: dict = field(default_factory=dict, repr=False)
     #: recorded execution downgrades, deduped by (site, reason, specs)
     _fallbacks: dict = field(default_factory=dict, repr=False)
     #: BCSR partition memo: (nid, nparts, id(data)) -> (data, ShardedBCSR)
@@ -583,6 +780,12 @@ class CompiledPlan:
 
     # -- staged whole-plan path --------------------------------------------
 
+    @functools.cached_property
+    def table(self) -> StepTable:
+        """The plan's steps under this layout and Pallas mode."""
+        with obs.span(obs.CODEGEN):
+            return plan_steps(self.plan, self.layout, self.pallas, self.cache)
+
     def staged_callable(self, args=None) -> tuple[Callable, Callable]:
         """(jitted whole-plan function, its un-jitted trace function),
         building them on first use.  Both take the graph's input arrays
@@ -602,15 +805,10 @@ class CompiledPlan:
                                                      tuple]:
         """(jitted, raw, whole-plan key) of the staged lowering whose Row
         kernels over the inputs at ``lanes`` run lane-major, built on
-        first use; the row-major lowering is always built first."""
-        if self._staged_fn is None:
-            self._staged_fn, self._staged_raw, self._staged_key = \
-                self._build_staged()
-        if not lanes:
-            return self._staged_fn, self._staged_raw, self._staged_key
-        hit = self._staged_lanes.get(lanes)
+        first use."""
+        hit = self._staged.get(lanes)
         if hit is None:
-            hit = self._staged_lanes[lanes] = self._build_staged(lanes)
+            hit = self._staged[lanes] = self._build_staged(lanes)
         return hit
 
     def lane_inputs(self, args) -> frozenset:
@@ -618,17 +816,48 @@ class CompiledPlan:
         lane-major: the input is the main of a local Row kernel that has
         a lane-major form, and its device stores it column-major
         (:func:`device_layout` (1, 0)), as a TPU stores an (m, n) f32
-        array whose n is not a multiple of 128."""
-        self._staged_for(frozenset())
-        return frozenset(p for p in self._lane_mains
-                         if device_layout(args[p]) == (1, 0))
+        array whose n is not a multiple of 128.  Records each such
+        kernel's orientation for :attr:`row_orientation`."""
+        layouts: dict[int, Optional[tuple]] = {}
+        for idx, pos, _reason in self.table.rows:
+            if pos is None:
+                continue
+            if pos not in layouts:
+                layouts[pos] = device_layout(args[pos])
+            layout = layouts[pos]
+            e = self._chosen[idx] = {"specs": [idx]}
+            if layout == (1, 0):
+                e["orientation"] = "lane_major"
+                continue
+            e["orientation"] = "row_major"
+            e["reason"] = (f"the main's device layout is not readable "
+                           f"(input {pos})" if layout is None else
+                           f"the main is stored major_to_minor {layout} "
+                           f"(input {pos})")
+        return frozenset(p for p, lay in layouts.items() if lay == (1, 0))
+
+    @property
+    def row_orientation(self) -> list:
+        """Each local Row kernel's orientation, as the last call chose
+        it, with the reason where it is row-major."""
+        return [dict(self._chosen.get(e["specs"][0], e))
+                for e in self.table.orientations()]
 
     def _build_staged(self, lanes: frozenset = frozenset()
                       ) -> tuple[Callable, Callable, tuple]:
+        table = self.table
+        real_mesh = _is_real_mesh(_mesh_of(self.layout))
+        for site, specs, reason in table.fallbacks:
+            if site != "kernel":
+                self.record_fallback(site, reason, specs=specs,
+                                     hard=real_mesh)
         with obs.span(obs.CODEGEN) as sp:
-            key, plan_fn = self._lower_staged(lanes)
+            plan_fn = self._lower_staged(table, lanes)
         if self.name is not None:
             plan_fn.__name__ = plan_fn.__qualname__ = self.name
+        key = table.key
+        if lanes:
+            key += (("rowt", tuple(sorted(lanes))),)
         # build-once under concurrency: racing threads compiling
         # structurally-equal plans share one jitted function (and with
         # it one XLA executable per shape signature)
@@ -640,149 +869,38 @@ class CompiledPlan:
             key, _build, extra_build_s=sp.seconds)
         return jitted, plan_fn, key
 
-    def _lower_staged(self, lanes: frozenset = frozenset()
-                      ) -> tuple[tuple, Callable]:
-        """(structural whole-plan key, un-jitted plan function).  Row
-        kernels whose main is the input at a position in ``lanes`` lower
-        lane-major; the key then names those positions."""
+    def _lower_staged(self, table: StepTable,
+                      lanes: frozenset) -> Callable:
+        """The un-jitted function that runs ``table``'s steps; Row
+        kernels whose main is the input at a position in ``lanes`` run
+        lane-major."""
         from repro.kernels.distributed import (
-            SegmentFallback, SegmentItem, lower_segment, plan_segment,
-            run_segment_local)
+            SegmentFallback, lower_segment, run_segment_local)
 
-        graph, plan = self.plan.graph, self.plan
-        specs = plan.specs
+        graph = self.plan.graph
         in_nids = tuple(n.nid for n in graph.inputs())
         lits = tuple((n.nid, float(n.attrs["value"]))
                      for n in graph.nodes if n.op == "lit")
         output_ids = tuple(o.nid for o in graph.outputs)
-        mesh = _mesh_of(self.layout)
-        real_mesh = _is_real_mesh(mesh)
-
-        # canonical env tokens: whole-plan keys must capture the wiring,
-        # not the node ids (structurally-equal plans from other traces
-        # must hit)
-        canon: dict[int, tuple] = {nid: ("in", p)
-                                   for p, nid in enumerate(in_nids)}
-        for nid, v in lits:
-            canon[nid] = ("lit", v)
-
-        steps: list[tuple] = []          # executable steps
-        key_parts: list[tuple] = []      # structural key, one per step
-        spec_step: dict[int, int] = {}   # spec idx -> step idx
-        self._seg_plans = []
-        in_pos = {nid: p for p, nid in enumerate(in_nids)}
-        lane_mains: dict[int, list[int]] = {}
-        row_orient: dict[int, dict] = {}
-
-        def _token(roots: tuple[int, ...], step_idx: int,
-                   item_idx: int = 0) -> None:
-            # the item index distinguishes the members of one segment
-            # step — without it two outputs of the same step would be
-            # indistinguishable in the whole-plan key and a structurally
-            # different consumer wiring could hit the wrong function
-            for k, r in enumerate(roots):
-                canon[r] = ("s", step_idx, item_idx, k)
-
-        def _seg_key(items, sp):
-            return ("seg", mesh,
-                    tuple((it.cplan.cache_key(), it.placement.epilogue,
-                           tuple(b.nid in it.placement.sharded
-                                 for b in it.cplan.binds), it.export)
-                          for it in items),
-                    tuple(canon[nid] for nid in sp.ext))
-
-        seg_start = {seg.indices[0]: seg for seg in plan.segments}
-        idx = 0
-        while idx < len(specs):
-            seg = seg_start.get(idx)
-            if seg is not None and mesh is not None:
-                items = _segment_items(graph, plan, seg, self.cache)
-                sp = plan_segment(items, mesh)
-                if isinstance(sp, SegmentFallback):
-                    # mesh can't realize the costed placement: record
-                    # and let the members run as local fused steps
-                    self.record_fallback("segment", sp.reason,
-                                         specs=tuple(seg.indices),
-                                         hard=real_mesh)
-                else:
-                    step_idx = len(steps)
-                    steps.append(("seg", sp,
-                                  tuple(it.roots for it in items
-                                        if it.export)))
-                    key_parts.append(_seg_key(items, sp))
-                    self._seg_plans.append(sp)
-                    for j in seg.indices:
-                        spec_step[j] = step_idx
-                    for item_idx, it in enumerate(items):
-                        _token(it.roots, step_idx, item_idx)
-                    idx = seg.indices[-1] + 1
-                    continue
-            spec = specs[idx]
-            step_idx = len(steps)
-            if isinstance(spec, MultiAggSpec) or (
-                    isinstance(spec, FusedOpSpec) and spec.fused):
-                _op, cplan = self.cache.get_or_build(graph, spec)
-                roots = _spec_roots(spec)
-                pl = getattr(spec, "placement", None)
-                sp = None
-                if pl is not None and pl.arm == "distributed" \
-                        and mesh is not None:
-                    items = [SegmentItem(cplan, pl, roots, True)]
-                    sp = plan_segment(items, mesh)
-                    if isinstance(sp, SegmentFallback):
-                        self.record_fallback("operator", sp.reason,
-                                             specs=(idx,), hard=real_mesh)
-                        sp = None
-                bind_nids = tuple(b.nid for b in cplan.binds)
-                if sp is not None:
-                    steps.append(("seg", sp, (roots,)))
-                    key_parts.append(_seg_key(items, sp))
-                    self._seg_plans.append(sp)
-                else:
-                    entry, main_pos = _row_entry(idx, cplan, in_pos,
-                                                 self.pallas)
-                    if entry is not None:
-                        row_orient[idx] = entry
-                    if main_pos is not None:
-                        lane_mains.setdefault(main_pos, []).append(idx)
-                    steps.append(("fused", cplan, bind_nids, roots,
-                                  main_pos in lanes))
-                    key_parts.append((
-                        "fused", cplan.cache_key(),
-                        tuple(canon[nid] for nid in bind_nids)))
-                _token(roots, step_idx)
-            else:
-                node = graph.by_id[spec.root]
-                steps.append(("basic", node))
-                key_parts.append((
-                    "basic", node.op,
-                    tuple(sorted(node.attrs.items())), node.shape,
-                    tuple(canon[i.nid] if i.op != "lit"
-                          else ("lit", float(i.attrs["value"]))
-                          for i in node.inputs)))
-                canon[spec.root] = ("s", step_idx, 0, 0)
-            spec_step[idx] = step_idx
-            idx += 1
-
-        # dead intermediates, re-indexed from spec positions to steps
-        free: dict[int, list[int]] = {}
-        for sidx, dead in _last_uses(plan).items():
-            step_idx = spec_step[sidx]
-            keep = set(output_ids)
-            free.setdefault(step_idx, []).extend(
-                d for d in dead if d not in keep)
-
-        pallas = self.pallas
+        mesh, pallas = _mesh_of(self.layout), self.pallas
+        steps, free = table.steps, table.free
 
         def _mat(v):
             # a value partitioned for a segment, consumed whole elsewhere
             return v.unshard() if isinstance(v, ShardedBCSR) else v
 
+        def _put(env, out, roots):
+            if len(roots) > 1:
+                for k, r in enumerate(roots):
+                    env[r] = out[k].reshape(1, 1)
+            else:
+                env[roots[0]] = out
+
         def plan_fn(*arrays):
             env: dict[int, object] = dict(zip(in_nids, arrays))
             for nid, v in lits:         # trace-time constants
                 env[nid] = jnp.full((1, 1), v, jnp.float32)
-            for step_idx, step in enumerate(steps):
+            for step, dead_after in zip(steps, free):
                 kind = step[0]
                 if kind == "seg":
                     _, sp, out_roots = step
@@ -798,61 +916,20 @@ class CompiledPlan:
                     else:
                         outs = lowered(*vals)
                     for out, roots in zip(outs, out_roots):
-                        if len(roots) > 1:
-                            for k, r in enumerate(roots):
-                                env[r] = out[k].reshape(1, 1)
-                        else:
-                            env[roots[0]] = out
+                        _put(env, out, roots)
                 elif kind == "fused":
-                    _, cplan, bind_nids, roots, lane_major = step
-                    out = kops.execute(
+                    _, cplan, bind_nids, roots, main_pos = step
+                    _put(env, kops.execute(
                         cplan, {nid: _mat(env[nid]) for nid in bind_nids},
-                        pallas=pallas, lanes=lane_major)
-                    if len(roots) > 1:
-                        for k, r in enumerate(roots):
-                            env[r] = out[k].reshape(1, 1)
-                    else:
-                        env[roots[0]] = out
+                        pallas=pallas, lanes=main_pos in lanes), roots)
                 else:
                     node = step[1]
                     env[node.nid] = _eval_basic(graph, node, env)
-                for dead in free.get(step_idx, ()):
+                for dead in dead_after:
                     env.pop(dead, None)      # release: XLA reuses buffers
             return tuple(_mat(env[o]) for o in output_ids)
 
-        key = (tuple(key_parts), tuple(canon[o] for o in output_ids),
-               self.pallas, tuple(getattr(self.plan, "rewrite", ()) or ()))
-        if lanes:
-            key += (("rowt", tuple(sorted(lanes))),)
-        else:
-            self._lane_mains, self._row_orient = lane_mains, row_orient
-        return key, plan_fn
-
-    @property
-    def row_orientation(self) -> list:
-        """Each local Row kernel's orientation, as the last call chose
-        it, with the reason where it is row-major."""
-        return [dict(e) for _i, e in sorted(self._row_orient.items())]
-
-    def _orient_rows(self, args) -> frozenset:
-        """The input positions whose Row kernels run lane-major in a
-        call with ``args`` (:meth:`lane_inputs`); records each Row
-        kernel's orientation."""
-        lanes = set()
-        for p, idxs in self._lane_mains.items():
-            layout = device_layout(args[p])
-            if layout == (1, 0):
-                lanes.add(p)
-            for idx in idxs:
-                e = self._row_orient[idx] = {"specs": [idx]}
-                e["orientation"] = "lane_major" if p in lanes else "row_major"
-                if layout is None:
-                    e["reason"] = (f"the main's device layout is not "
-                                   f"readable (input {p})")
-                elif p not in lanes:
-                    e["reason"] = (f"the main is stored major_to_minor "
-                                   f"{layout} (input {p})")
-        return frozenset(lanes)
+        return plan_fn
 
     def batched_callable(self) -> Callable:
         """Jitted ``vmap`` of the staged whole-plan function over a new
@@ -869,8 +946,8 @@ class CompiledPlan:
             raise PlanInvariantError(
                 "batched_callable: batched (vmapped) execution requires "
                 "a mesh-free plan; this plan was compiled under a layout")
-        _fn, raw = self.staged_callable()
-        key = ("vmap", self._staged_key)
+        _fn, raw, key = self._staged_for(frozenset())
+        key = ("vmap", key)
 
         def _build():
             faults.fault_point("plan.jit_build")
@@ -916,8 +993,7 @@ class CompiledPlan:
 
         last_use = _last_uses(self.plan)
         for idx, spec in enumerate(self.plan.specs):
-            if isinstance(spec, MultiAggSpec) or (
-                    isinstance(spec, FusedOpSpec) and spec.fused):
+            if _fused(spec):
                 op, my_cplan = self.cache.get_or_build(graph, spec)
                 out = self._dist_call(idx, spec, my_cplan, env)
                 if out is None:
@@ -962,7 +1038,10 @@ class CompiledPlan:
         BCSRs that a ``shard_map`` segment consumes row-sharded (must run
         outside ``jit`` — re-bucketing needs concrete indices), recording
         every operand that forces the segment to run locally instead."""
-        for sp in self._seg_plans:
+        for step in self.table.steps:
+            if step[0] != "seg":
+                continue
+            sp = step[1]
             sparse_noagg = {it.cplan.main.nid for it in sp.items
                             if it.export and it.cplan.variant == NO_AGG
                             and it.cplan.main.exploit}
@@ -1003,70 +1082,16 @@ class CompiledPlan:
                 raise KeyError(f"missing binding for input '{node.name}'")
         if not self.staged:
             return self._call_per_op(bindings)
-        self._staged_for(frozenset())    # the lowering preflight reads
         vals = {n.nid: bindings[n.name] for n in graph.inputs()}
         self._prepare_inputs(vals)
         args = [vals[n.nid] for n in graph.inputs()]
-        fn, _raw, key = self._staged_for(self._orient_rows(args))
+        fn, _raw, key = self._staged_for(self.lane_inputs(args))
         if WHOLE_PLAN_CACHE.first_call(key, args):
             with obs.span(obs.STAGE):
                 outs = fn(*args)
         else:
             outs = fn(*args)
         return outs[0] if len(outs) == 1 else tuple(outs)
-
-
-
-
-def _row_entry(idx: int, cplan: CPlan, in_pos: dict,
-               pallas: str) -> tuple[Optional[dict], Optional[int]]:
-    """(orientation entry, input position of the main where the kernel
-    may run lane-major) of the local fused operator ``idx``; (None,
-    None) where it is no dense Row kernel."""
-    if pallas == "never" or cplan.ttype != TType.ROW or cplan.extra:
-        return None, None
-    shapes = {b.nid: tuple(b.shape) for b in cplan.binds}
-    if kops.kernel_fallback(cplan, shapes) is not None:
-        return None, None               # XLA body in either orientation
-    reason = (lane_forms(cplan)[1]
-              or kops.kernel_fallback(cplan, shapes, lanes=True))
-    if reason is None and cplan.main.nid not in in_pos:
-        reason = (f"main %{cplan.main.nid} is computed inside the plan: "
-                  f"its device layout is not observed")
-    if reason is not None:
-        return {"specs": [idx], "orientation": "row_major",
-                "reason": reason}, None
-    return ({"specs": [idx], "orientation": "by_layout"},
-            in_pos[cplan.main.nid])
-
-
-def row_orientations(plan: ExecPlan, pallas: str = "never", layout=None,
-                     cache: Optional[PlanCache] = None) -> list:
-    """The static part of ``explain()["execution"]["row_orientation"]``:
-    one entry per local dense Row kernel, row-major with the reason, or
-    ``by_layout`` where each call's main decides (lane-major where the
-    device stores it column-major).  Operators a
-    mesh runs inside ``shard_map`` keep the row-major lowering and are
-    not listed."""
-    cache = cache if cache is not None else PLAN_CACHE
-    graph = plan.graph
-    in_pos = {n.nid: p for p, n in enumerate(graph.inputs())}
-    sharded = set()
-    if _mesh_of(layout) is not None:
-        sharded = {j for seg in plan.segments for j in seg.indices}
-        sharded |= {j for j, s in enumerate(plan.specs)
-                    if getattr(getattr(s, "placement", None), "arm",
-                               None) == "distributed"}
-    out = []
-    for idx, spec in enumerate(plan.specs):
-        if idx in sharded or not (isinstance(spec, MultiAggSpec) or (
-                isinstance(spec, FusedOpSpec) and spec.fused)):
-            continue
-        _op, cplan = cache.get_or_build(graph, spec)
-        entry, _pos = _row_entry(idx, cplan, in_pos, pallas)
-        if entry is not None:
-            out.append(entry)
-    return out
 
 
 def device_layout(v) -> Optional[tuple]:
@@ -1115,125 +1140,31 @@ def _last_uses(plan: ExecPlan) -> dict[int, list[int]]:
 
 def staged_plan_key(plan: ExecPlan, pallas: str = "never",
                     cache: Optional[PlanCache] = None) -> tuple:
-    """The structural whole-plan cache key of the local (mesh-free)
-    staged lowering, computed without tracing or jitting anything —
-    the replay the plan verifier's key-completeness check
-    (:func:`repro.core.verify.verify_exec`, EXE004) runs: every value a
-    step consumes must resolve to a canonical env token, so a
-    ``KeyError`` here means the plan wires a value no step produces.
-
-    Mirrors the mesh-free path of :meth:`CompiledPlan._build_staged`
-    (same token scheme, same key layout) — keep the two in sync."""
-    cache = cache if cache is not None else PLAN_CACHE
-    graph = plan.graph
-    in_nids = tuple(n.nid for n in graph.inputs())
-    output_ids = tuple(o.nid for o in graph.outputs)
-    canon: dict[int, tuple] = {nid: ("in", p)
-                               for p, nid in enumerate(in_nids)}
-    for n in graph.nodes:
-        if n.op == "lit":
-            canon[n.nid] = ("lit", float(n.attrs["value"]))
-
-    key_parts: list[tuple] = []
-    for spec in plan.specs:
-        step_idx = len(key_parts)
-        if isinstance(spec, MultiAggSpec) or (
-                isinstance(spec, FusedOpSpec) and spec.fused):
-            _op, cplan = cache.get_or_build(graph, spec)
-            bind_nids = tuple(b.nid for b in cplan.binds)
-            key_parts.append(("fused", cplan.cache_key(),
-                              tuple(canon[nid] for nid in bind_nids)))
-            for k, r in enumerate(_spec_roots(spec)):
-                canon[r] = ("s", step_idx, 0, k)
-        else:
-            node = graph.by_id[spec.root]
-            key_parts.append((
-                "basic", node.op,
-                tuple(sorted(node.attrs.items())), node.shape,
-                tuple(canon[i.nid] if i.op != "lit"
-                      else ("lit", float(i.attrs["value"]))
-                      for i in node.inputs)))
-            canon[spec.root] = ("s", step_idx, 0, 0)
-    return (tuple(key_parts), tuple(canon[o] for o in output_ids), pallas,
-            tuple(getattr(plan, "rewrite", ()) or ()))
+    """The structural whole-plan cache key of the mesh-free staged
+    lowering (:func:`plan_steps`), computed without tracing or jitting
+    anything: the server's bucketing identity and the verifier's
+    key-completeness check (:func:`repro.core.verify.verify_exec`,
+    EXE004)."""
+    return plan_steps(plan, None, pallas, cache).key
 
 
 def plan_fallbacks(plan: ExecPlan, layout=None, pallas: str = "never",
                    staged: bool = True,
                    cache: Optional[PlanCache] = None) -> list:
     """Statically derivable execution downgrades for this plan — the
-    compile-time portion of ``explain()['execution']['fallbacks']``.
-
-    Replays the same :func:`~repro.kernels.distributed.plan_segment`
-    validation the staged lowering runs (via the shared
-    :func:`_segment_items`), so the report can never drift from what
-    execution does.  Under a Pallas policy it also names every dense
-    operator that takes its XLA body instead of its kernel (``kernel``
-    site, :func:`~repro.kernels.ops.kernel_fallback` at the shapes the
-    kernel would see — shard-local inside a segment).  Value-format
-    downgrades (a sparse operand whose block rows don't partition)
-    depend on the bound arrays and are recorded at call time on
-    :attr:`CompiledPlan.fallbacks`;
-    ``Compiled.explain()`` merges both."""
-    cache = cache if cache is not None else PLAN_CACHE
-    out: list[dict] = []
-    if not staged:
-        out.append({"site": "plan",
-                    "reason": "staged=False: per-operator debug "
-                              "dispatch requested"})
-    graph = plan.graph
-    #: operand shapes each fused operator's kernel sees (shard-local
-    #: inside realizable segments)
-    shapes: dict[int, dict] = {}
-    mesh = _mesh_of(layout)
-    if mesh is not None:
-        from repro.kernels.distributed import (SegmentFallback, SegmentItem,
-                                               plan_segment)
-        seg_member = {j for seg in plan.segments for j in seg.indices}
-        for seg in plan.segments:
-            items = _segment_items(graph, plan, seg, cache)
-            sp = plan_segment(items, mesh)
-            if isinstance(sp, SegmentFallback):
-                out.append({"site": "segment", "specs": list(seg.indices),
-                            "reason": sp.reason})
-            else:
-                for k, j in enumerate(seg.indices):
-                    shapes[j] = sp.local_shapes(k)
-        for idx, spec in enumerate(plan.specs):
-            if idx in seg_member:
-                continue
-            pl = getattr(spec, "placement", None)
-            if pl is None or pl.arm != "distributed":
-                continue
-            _op, cplan = cache.get_or_build(graph, spec)
-            sp = plan_segment(
-                [SegmentItem(cplan, pl, _spec_roots(spec), True)], mesh)
-            if isinstance(sp, SegmentFallback):
-                out.append({"site": "operator", "specs": [idx],
-                            "reason": sp.reason})
-            else:
-                shapes[idx] = sp.local_shapes(0)
-    for idx, spec in enumerate(plan.specs):
-        if pallas == "never" or not (isinstance(spec, MultiAggSpec) or (
-                isinstance(spec, FusedOpSpec) and spec.fused)):
-            continue
-        _op, cplan = cache.get_or_build(graph, spec)
-        if cplan.main.sparsity < 1.0:
-            continue            # the planner saw a sparse main: BCSR rows
-        reason = kops.kernel_fallback(cplan, shapes.get(idx) or {
-            b.nid: tuple(b.shape) for b in cplan.binds})
-        if reason is not None:
-            out.append({"site": "kernel", "specs": [idx], "reason": reason})
-    return out
+    compile-time portion of ``explain()['execution']['fallbacks']``
+    (:meth:`StepTable.fallback_report`).  Value-format downgrades (a
+    sparse operand whose block rows don't partition) depend on the bound
+    arrays and are recorded at call time on
+    :attr:`CompiledPlan.fallbacks`; ``Compiled.explain()`` merges both."""
+    return plan_steps(plan, layout, pallas, cache).fallback_report(staged)
 
 
-def freed_intermediates(plan: ExecPlan) -> int:
-    """Number of intermediate values the staged trace releases at their
-    last use (graph outputs excepted) — the plan-level buffer-donation
-    count ``explain()`` reports."""
-    outs = set(plan.graph.output_ids)
-    return sum(1 for dead in _last_uses(plan).values()
-               for d in dead if d not in outs)
+def row_orientations(plan: ExecPlan, pallas: str = "never", layout=None,
+                     cache: Optional[PlanCache] = None) -> list:
+    """The static part of ``explain()["execution"]["row_orientation"]``
+    (:meth:`StepTable.orientations`)."""
+    return plan_steps(plan, layout, pallas, cache).orientations()
 
 
 def compile_plan(plan: ExecPlan, pallas: str = "never",

@@ -740,9 +740,10 @@ def verify_exec(eplan, strict: bool = False, pallas: str = "never",
 
 
 def _verify_exec_fallbacks(eplan, layout) -> list[Diagnostic]:
-    """EXE005 (no-silent-fallback): replay the distributed lowering's
-    plan-time validation (:func:`repro.core.codegen.plan_fallbacks`) and
-    report every placement a *real* mesh cannot realize as an error —
+    """EXE005 (no-silent-fallback): read the staged lowering's plan-time
+    downgrades (:func:`repro.core.codegen.plan_fallbacks`, the step
+    table the lowering runs) and report every placement a *real* mesh
+    cannot realize as an error —
     the runtime would downgrade those segments to local execution, so
     the plan's distributed cost priced a path execution never takes.
     On an abstract ``LogicalMesh`` the same downgrades are by design

@@ -237,7 +237,7 @@ assert [(o["template"], o.get("placement")) for o in ops] \\
 
 compiled = planned.compile(pallas="interpret")
 out = compiled(X=X, U=U, V=V)
-assert compiled._cplan._staged_fn is not None          # staged, not per-op
+assert compiled._cplan._staged          # staged, not per-op
 assert compiled._cplan.fallbacks == [], compiled._cplan.fallbacks
 assert compiled.explain()["execution"]["fallbacks"] == []
 

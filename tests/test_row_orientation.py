@@ -97,6 +97,34 @@ def test_tracer_keeps_row_major():
     assert e["orientation"] == "row_major" and "not readable" in e["reason"]
 
 
+def test_explain_lists_row_kernels_of_distributed_specs_run_locally():
+    """Under an abstract mesh the ``distributed`` Row spec runs as a
+    local fused kernel, so ``explain()`` lists its orientation, before a
+    call and after one whose main is stored column-major."""
+    from repro.dist import LogicalMesh
+    X, w, y = _operands(4096)
+    region = fused(lambda X, w, y: X.T @ (ir.relu(1.0 - y * (X @ w)) * y))
+    compiled = region.trace(X, w, y).plan(context=FusionContext(
+        pallas="interpret", layout=LogicalMesh({"data": 4}))).compile()
+    (idx,) = [j for j, s in enumerate(compiled.planned.eplan.specs)
+              if getattr(getattr(s, "placement", None), "arm", None)
+              == "distributed"]
+    assert any(f["site"] == "operator"
+               for f in compiled.explain()["execution"]["fallbacks"])
+
+    def entry():
+        (e,) = [e for e in compiled.explain()["execution"]["row_orientation"]
+                if e["specs"] == [idx]]
+        return e
+
+    assert entry() == {"specs": [idx], "orientation": "by_layout"}
+    got = compiled(_col(X), w, _col(y))
+    assert entry() == {"specs": [idx], "orientation": "lane_major"}
+    want = X.T @ (jnp.maximum(1.0 - y * (X @ w), 0.0) * y)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-3)
+
+
 def test_vmap_path_keeps_row_major():
     """The serving tier's batched path vmaps the row-major lowering."""
     compiled, (X, w, y) = _compiled(1064)
@@ -121,11 +149,14 @@ def test_staged_key_separates_orientations():
     fn_cols, _ = cp.staged_callable(cols)
     assert fn_rows is not fn_cols
     assert fn_rows is cp.staged_callable()[0]
-    (lanes,) = cp._staged_lanes
-    key_cols = cp._staged_lanes[lanes][2]
-    assert key_cols[:-1] == cp._staged_key
+    assert len(cp._staged) == 2
+    lanes = cp.lane_inputs(cols)
+    key_rows = cp._staged[frozenset()][2]
+    key_cols = cp._staged[lanes][2]
+    assert key_rows == cp.table.key
+    assert key_cols[:-1] == key_rows
     assert key_cols[-1] == ("rowt", tuple(sorted(lanes)))
-    assert WHOLE_PLAN_CACHE.get(cp._staged_key) is fn_rows
+    assert WHOLE_PLAN_CACHE.get(key_rows) is fn_rows
     assert WHOLE_PLAN_CACHE.get(key_cols) is fn_cols
 
 

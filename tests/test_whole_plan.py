@@ -118,7 +118,7 @@ def test_bcsr_compiles_staged_and_agrees():
     compiled = planned.compile(staged=True)
     got = compiled(X, B)
     _close(got, jnp.asarray(Xd.T) @ B, tol=2e-4)
-    assert compiled._cplan._staged_fn is not None   # staged, not per-op
+    assert compiled._cplan._staged      # staged, not per-op
     assert compiled._cplan.fallbacks == []
     assert compiled.explain()["execution"]["fallbacks"] == []
 
@@ -131,7 +131,7 @@ def test_pallas_interpret_compiles_staged():
     planned = f.trace(X, Y).plan(mode="gen")
     compiled = planned.compile(pallas="interpret")
     _close(compiled(X, Y), jnp.sum(X * Y + 1.0).reshape(1, 1), tol=2e-4)
-    assert compiled._cplan._staged_fn is not None
+    assert compiled._cplan._staged
     assert compiled._cplan.fallbacks == []
 
 
